@@ -175,7 +175,7 @@ def breaker_segment(seed: int) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# segment 3: kill mid-run, resume crash-consistently
+# segment 3: kill mid-run (first, before JAX), resume crash-consistently
 # ---------------------------------------------------------------------------
 
 def child_run(workdir: str, levels: int, n_steps: int, kill_at: int,
@@ -204,11 +204,16 @@ def child_run(workdir: str, levels: int, n_steps: int, kill_at: int,
     raise SystemExit("chaos child survived past kill_at — kill never fired")
 
 
-def kill_resume_segment(workdir: str, levels: int, seed: int) -> dict:
-    cfg = HydroConfig(subgrid=8, ghost=3, levels=levels)
-    st = sedov_init(cfg)
-    dt = courant_dt(st.u, cfg)
-    n_steps, kill_at = 4, 2
+N_STEPS, KILL_AT = 4, 2      # the kill target's trajectory and kill point
+
+
+def kill_segment(workdir: str, levels: int, seed: int) -> str:
+    """Run the kill target in a child process and check that it died with
+    checkpoint ``KILL_AT`` on disk; returns the saved fault schedule.
+
+    Runs before this process touches JAX: an accelerator belongs to one
+    process at a time, and a parent that had initialized a backend would
+    hold the chip the child needs."""
     # a previous soak's resumed checkpoints would shadow this run's kill
     # point (latest_step takes the max) — every soak starts from scratch
     shutil.rmtree(os.path.join(workdir, "ckpt"), ignore_errors=True)
@@ -220,16 +225,25 @@ def kill_resume_segment(workdir: str, levels: int, seed: int) -> dict:
     env["PYTHONPATH"] = SRC_PATH + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", workdir,
-         "--levels", str(levels), "--n-steps", str(n_steps),
-         "--kill-at", str(kill_at), "--checkpoint-every", "1"],
+         "--levels", str(levels), "--n-steps", str(N_STEPS),
+         "--kill-at", str(KILL_AT), "--checkpoint-every", "1"],
         env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode != 0, \
         f"chaos child exited cleanly — the mid-run kill never fired:\n" \
         f"{proc.stdout}\n{proc.stderr}"
     ckpt_dir = os.path.join(workdir, "ckpt")
-    assert latest_step(ckpt_dir) == kill_at, \
-        f"expected checkpoint {kill_at}, found {latest_step(ckpt_dir)}:\n" \
+    assert latest_step(ckpt_dir) == KILL_AT, \
+        f"expected checkpoint {KILL_AT}, found {latest_step(ckpt_dir)}:\n" \
         f"{proc.stdout}\n{proc.stderr}"
+    return schedule
+
+
+def resume_segment(workdir: str, levels: int, schedule: str) -> dict:
+    cfg = HydroConfig(subgrid=8, ghost=3, levels=levels)
+    st = sedov_init(cfg)
+    dt = courant_dt(st.u, cfg)
+    n_steps, kill_at = N_STEPS, KILL_AT
+    ckpt_dir = os.path.join(workdir, "ckpt")
 
     # resume in THIS process, replaying the same fault schedule (faults
     # fire on both sides of the crash; all of them are survivable)
@@ -388,13 +402,14 @@ def main() -> None:
     if args.smoke:
         args.steps, args.repeats = 1, 1
     levels = args.levels
+    workdir = os.path.abspath(args.workdir)
+    schedule = kill_segment(workdir, levels, args.seed)
     print(f"chaos_soak: seed={args.seed}, backend={jax.default_backend()}")
 
     faults, extras = {}, {}
     faults.update(wave_segment(args.seed))
     faults.update(breaker_segment(args.seed))
-    resume_info = kill_resume_segment(os.path.abspath(args.workdir), levels,
-                                      args.seed)
+    resume_info = resume_segment(workdir, levels, schedule)
     faults.update(resume_info["faults"])
     serving_info = serving_segment(args.seed)
     faults.update(serving_info["faults"])

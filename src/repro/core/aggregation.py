@@ -61,6 +61,7 @@ from repro.configs.base import (
     AggregationConfig, resolve_family_option, validate_ladder,
 )
 from repro.core.buffers import DEFAULT_POOL, BufferPool, SlotRing
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.executor import ExecutorPool
 from repro.core.faults import (
     BucketCompileError, FaultInjector, LaunchFaultError, LaunchTimeoutError,
@@ -1116,7 +1117,7 @@ class AggregationExecutor:
         self._prior: Optional[RooflinePrior] = None
         self._prior_on = prior_mode == "roofline"
         if self._store is not None:
-            self._store.enable_compilation_cache()
+            enable_compile_cache()
         # blast-radius containment (DESIGN.md §11)
         self._guard = getattr(self.config, "guard", "off")
         if self._guard not in ("off", "finite"):
@@ -1297,7 +1298,7 @@ class AggregationExecutor:
         if store is not None:
             self._store = TuneStore.open(store)
             if self._store is not None:
-                self._store.enable_compilation_cache()
+                enable_compile_cache()
 
         def aot_buckets(region):
             want = region.buckets if buckets is None else tuple(buckets)

@@ -46,10 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.4.35 moved shard_map
-    from jax.experimental.shard_map import shard_map
-except ImportError:                     # pragma: no cover - newer jax
-    from jax.shard_map import shard_map
+from jax import shard_map
 
 from repro.configs.base import AggregationConfig
 from repro.core.aggregation import (RangeFuture, TaskFuture, TaskSignature,
@@ -632,7 +629,9 @@ class TenantBatcher:
         if not self._eager:
             try:
                 stage = self._stage_for(states)
-            except Exception:
+            except (jax.errors.JAXTypeError, jax.errors.JAXIndexError):
+                # populations/assemble do not trace; anything else (an
+                # out-of-memory or runtime error on the device) surfaces
                 self._eager = True
                 stage = None
             if stage is not None:

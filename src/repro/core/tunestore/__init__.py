@@ -4,9 +4,10 @@ Two halves, both feeding the same :class:`~repro.core.aggregation.
 BucketCostModel` currency:
 
 * :class:`TuneStore` — the on-disk table of everything a tuned process
-  knows (cost tables, ladders, inner chunks, strategy selections), plus
-  the JAX persistent-compilation-cache hookup, so process two measures
-  nothing and recompiles nothing;
+  knows (cost tables, ladders, inner chunks, strategy selections); a
+  configured store also turns on the persistent compilation cache
+  (``repro.core.compile_cache``), so process two measures nothing and
+  recompiles nothing;
 * :class:`RooflinePrior` — the analytical fallback for process ONE, so
   an empty store still yields a sane ladder without zero-fill timing.
 """

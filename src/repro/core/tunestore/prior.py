@@ -12,7 +12,7 @@ the family's argument shapes/dtypes (inputs read + ``jax.eval_shape``'d
 outputs written, scaled by the bucket); ``flops`` comes from XLA's own
 cost analysis of the bucket-1 program when available (one lowering, zero
 launches), falling back to a fixed arithmetic-intensity guess.  Device
-peaks come from a small table keyed by ``device_kind``; unknown devices
+peaks come from a small table keyed by exact ``device_kind``; unknown devices
 get a measured-once micro-benchmark (one bandwidth op, one matmul, one
 empty launch — memoized for the process).
 
@@ -32,18 +32,19 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-# device_kind substring (lowercased) -> (bytes/s, flop/s, launch seconds).
-# Deliberately coarse: sustained streaming numbers, not datasheet peaks,
-# because the prior's job is ladder SHAPE, not absolute wall time.  "cpu"
-# must stay in this table so CPU-only CI never pays the calibration run.
+# exact ``device_kind`` -> (bytes/s, flop/s, launch seconds), each row with
+# its source.  A kind that is not here (another TPU generation, a GPU) is
+# calibrated once by the micro-benchmark below instead of borrowing a
+# neighbour's peaks.  "cpu" stays in the table so CPU-only CI never pays
+# the calibration run.
 DEVICE_PEAKS: Dict[str, Tuple[float, float, float]] = {
-    "cpu":        (2.0e10, 5.0e10, 2.0e-5),
-    "tpu v5":     (8.0e11, 2.0e14, 5.0e-5),
-    "tpu v4":     (1.2e12, 2.7e14, 5.0e-5),
-    "tpu":        (7.0e11, 1.0e14, 5.0e-5),
-    "h100":       (3.0e12, 5.0e14, 1.0e-5),
-    "a100":       (1.5e12, 1.5e14, 1.0e-5),
-    "gpu":        (8.0e11, 5.0e13, 1.0e-5),
+    # XLA:CPU host: a coarse sustained-streaming estimate, not a datasheet
+    # figure (the prior needs the ladder's shape, not absolute time)
+    "cpu":         (2.0e10, 5.0e10, 2.0e-5),
+    # TPU v5e: Google Cloud documentation "TPU v5e" -- 819 GB/s HBM,
+    # 197 TFLOP/s bf16 (the MXU peak; f32 vector-unit stencils run far
+    # below it).  Launch overhead is an estimate, not measured.
+    "TPU v5 lite": (8.19e11, 1.97e14, 5.0e-5),
 }
 
 # flops per element when XLA's cost analysis is unavailable: a band
@@ -52,14 +53,6 @@ FALLBACK_FLOPS_PER_ELEM = 16.0
 
 # measured-once calibration memo: backend key -> (bw, flops, launch)
 _CALIBRATION: Dict[Tuple[str, str], Tuple[float, float, float]] = {}
-
-
-def _lookup_peaks(device_kind: str) -> Optional[Tuple[float, float, float]]:
-    kind = (device_kind or "").lower()
-    for key, peaks in DEVICE_PEAKS.items():
-        if key in kind:
-            return peaks
-    return None
 
 
 def _microbenchmark() -> Tuple[float, float, float]:
@@ -97,8 +90,8 @@ def _microbenchmark() -> Tuple[float, float, float]:
 
 def device_peaks(backend_key: Tuple[str, ...]) -> Tuple[float, float, float]:
     """(bytes/s, flop/s, launch seconds) for the keyed device: table hit
-    by ``device_kind`` substring, else the memoized micro-benchmark."""
-    known = _lookup_peaks(backend_key[1])
+    by exact ``device_kind``, else the memoized micro-benchmark."""
+    known = DEVICE_PEAKS.get(backend_key[1])
     if known is not None:
         return known
     cal = _CALIBRATION.get(backend_key)

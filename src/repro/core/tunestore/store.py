@@ -22,9 +22,10 @@ Keying (staleness = a key mismatch, never a guess):
 
 Writes go through a same-directory temp file + ``os.replace`` so a
 concurrent reader sees either the old store or the new one, never a
-torn JSON.  The store directory also hosts the JAX persistent
-compilation-cache dir (``xla-cache/``), so one ``tune_store=`` knob
-removes both re-measurement AND re-compilation from process two.
+torn JSON.  Configuring a store also turns on JAX's persistent
+compilation cache (``repro.core.compile_cache``, which owns its place),
+so one ``tune_store=`` knob removes both re-measurement AND
+re-compilation from process two.
 """
 from __future__ import annotations
 
@@ -37,7 +38,6 @@ from typing import Any, Dict, Optional, Tuple
 
 SCHEMA_VERSION = 1
 STORE_FILENAME = "tunestore.json"
-XLA_CACHE_DIRNAME = "xla-cache"
 
 # env var consulted when no explicit ``tune_store`` path is configured —
 # the production-serving knob: point every process of a deployment at one
@@ -46,11 +46,6 @@ STORE_ENV_VAR = "REPRO_TUNE_STORE"
 
 _SALT_SOURCES = ("aggregation.py",)   # relative to repro/core
 _code_salt_memo: Optional[str] = None
-
-# process-global set of cache dirs already handed to jax.config — the
-# compilation cache dir is process-wide state; flipping it per executor
-# would thrash the cache without buying anything
-_COMPILE_CACHE_ENABLED: set = set()
 
 
 def code_salt() -> str:
@@ -234,38 +229,3 @@ class TuneStore:
     def __len__(self) -> int:
         self._ensure_loaded()
         return len(self._entries)
-
-    # -- the compilation half of warm start --------------------------------
-    @property
-    def xla_cache_dir(self) -> str:
-        return os.path.join(self.root, XLA_CACHE_DIRNAME)
-
-    def enable_compilation_cache(self) -> bool:
-        """Point JAX's persistent compilation cache at this store's
-        ``xla-cache/`` dir, so process two's bucket AOT compiles are disk
-        hits instead of XLA recompiles.  Thresholds are dropped to zero —
-        bucket programs are small but numerous, which is exactly the
-        population the default min-compile-time filter would skip.
-        Process-global and idempotent; returns whether the cache is on."""
-        if self.xla_cache_dir in _COMPILE_CACHE_ENABLED:
-            return True
-        try:
-            import jax
-            os.makedirs(self.xla_cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir",
-                              self.xla_cache_dir)
-            for flag, val in (
-                    ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                    ("jax_persistent_cache_min_entry_size_bytes", -1)):
-                try:
-                    jax.config.update(flag, val)
-                except (AttributeError, ValueError):
-                    pass          # older jax: keep its default thresholds
-        except Exception as err:  # cache is an optimization, never fatal
-            warnings.warn(
-                f"could not enable the JAX persistent compilation cache "
-                f"at {self.xla_cache_dir}: {err}",
-                TuneStoreWarning, stacklevel=2)
-            return False
-        _COMPILE_CACHE_ENABLED.add(self.xla_cache_dir)
-        return True

@@ -51,8 +51,6 @@ def make_dp_train_step(loss_fn: Callable, opt_cfg: OptConfig, mesh: Mesh,
                        axis: str = "data", compress: bool = True):
     """shard_map DP train step: per-shard grads, (optionally int8) all-reduce,
     replicated update.  ``loss_fn(params, batch) -> scalar``."""
-    from jax.experimental.shard_map import shard_map
-
     def step(params, opt_state, residual, batch):
         def shard_body(params, opt_state, residual, batch):
             loss, grads = jax.value_and_grad(
@@ -81,12 +79,12 @@ def make_dp_train_step(loss_fn: Callable, opt_cfg: OptConfig, mesh: Mesh,
         param_spec = jax.tree_util.tree_map(lambda _: rep, params)
         opt_spec = jax.tree_util.tree_map(lambda _: rep, opt_state)
         res_spec = jax.tree_util.tree_map(lambda _: rep, residual)
-        return shard_map(
+        return jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(param_spec, opt_spec, res_spec, batch_spec),
             out_specs=(param_spec, opt_spec, res_spec, rep,
                        {"grad_norm": rep, "lr": rep}),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, residual, batch)
 
     return jax.jit(step, donate_argnums=(0, 1, 2))

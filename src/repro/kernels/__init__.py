@@ -2,7 +2,8 @@
 
 Each kernel has three pieces:
   <name>.py — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling (TPU target)
-  ops.py    — jit'd public wrapper with backend dispatch (interpret on CPU)
+  ops.py    — jit'd public wrapper (Mosaic on TPU, interpret elsewhere:
+              backend.py makes that choice for every kernel)
   ref.py    — pure-jnp oracle used for allclose validation and as the
               production XLA path where the kernel isn't warranted
 

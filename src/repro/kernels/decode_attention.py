@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -62,8 +64,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     cache_len: jax.Array, *, bs: int = 512,
-                     interpret: bool = True) -> jax.Array:
+                     cache_len: jax.Array, *, bs: int = 512) -> jax.Array:
     """(B, Hq, D) x (B, S, Hkv, D) caches -> (B, Hq, D)."""
     b, hq, d = q.shape
     s, hkv = k_cache.shape[1], k_cache.shape[2]
@@ -73,7 +74,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     n_s = s // bs
     scale = 1.0 / (d ** 0.5)
     qg = q.reshape(b, hkv, g, d)
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_decode_kernel, bs=bs, n_s=n_s, scale=scale),
         grid=(b, hkv, n_s),
         in_specs=[
@@ -89,6 +90,5 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        interpret=interpret,
     )(cache_len, qg, k_cache, v_cache)
     return out.reshape(b, hq, d)

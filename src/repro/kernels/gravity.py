@@ -17,8 +17,9 @@ body opens its own ``TaskSignature`` family — distinct from hydro's by
 kernel id — when both are submitted to one ``AggregationExecutor``.
 
 The Pallas twin (``gravity_pallas``, slot_grid layout) runs the same block
-math with the aggregated-task axis as the kernel grid, validated bit-exact
-against the jnp oracle in interpret mode (tests/test_gravity.py).
+math with the aggregated-task axis as the kernel grid: Mosaic-compiled on
+TPU, and validated bit-exact against the jnp oracle in interpret mode
+elsewhere (tests/test_gravity.py).
 """
 from __future__ import annotations
 
@@ -28,6 +29,9 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import pallas_call
 
 
 def _interior_mask(p: int):
@@ -127,45 +131,50 @@ def gravity_batched_jit(ghost: int, subgrid: int, g_const: float = 1.0,
 # Pallas kernel (slot_grid layout, per-slot traced h)
 # ---------------------------------------------------------------------------
 
-def _kernel_gravity_slot_grid_h(u_ref, h_ref, out_ref, *, ghost, subgrid,
+# Scoped-VMEM limit: one padded sub-grid's relaxation sweeps need under
+# 1 MB at 8^3 and 1.14 MB at 16^3 (compiled for v5e), far inside it.
+GRAVITY_VMEM_BYTES = 16 << 20
+
+
+def _kernel_gravity_slot_grid_h(h_ref, u_ref, out_ref, *, ghost, subgrid,
                                 g_const, n_iter):
     u = u_ref[0]                                  # (F, P, P, P)
-    h = h_ref[0, 0]
+    h = h_ref[pl.program_id(0)]
     out_ref[0] = _gravity_block(u[0], h, ghost=ghost, subgrid=subgrid,
                                 g_const=g_const, n_iter=n_iter)
 
 
 def gravity_pallas(u_slots: jax.Array, h_slots: jax.Array, *, ghost: int,
-                   subgrid: int, g_const: float = 1.0, n_iter: int = 8,
-                   interpret: bool = True) -> jax.Array:
+                   subgrid: int, g_const: float = 1.0,
+                   n_iter: int = 8) -> jax.Array:
     """Aggregated gravity kernel: (slots, F, P, P, P) -> (slots, 4, S, S, S).
 
     slot_grid layout (one task per grid step, as in ``hydro_rhs_pallas``);
-    per-slot cell widths stage through SMEM-shaped ``(1, 1)`` blocks.
+    per-slot cell widths are scalar-prefetched into SMEM.
     """
     n, f, p = u_slots.shape[0], u_slots.shape[1], u_slots.shape[2]
     s = subgrid
-    h2d = jnp.reshape(h_slots, (n, 1))
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel_gravity_slot_grid_h, ghost=ghost,
                           subgrid=subgrid, g_const=g_const, n_iter=n_iter),
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, f, p, p, p), lambda i: (i, 0, 0, 0, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 4, s, s, s), lambda i: (i, 0, 0, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n,),
+            in_specs=[pl.BlockSpec((1, f, p, p, p),
+                                   lambda i, h_ref: (i, 0, 0, 0, 0))],
+            out_specs=pl.BlockSpec((1, 4, s, s, s),
+                                   lambda i, h_ref: (i, 0, 0, 0, 0))),
         out_shape=jax.ShapeDtypeStruct((n, 4, s, s, s), u_slots.dtype),
-        interpret=interpret,
-    )(u_slots, h2d)
+        vmem_limit_bytes=GRAVITY_VMEM_BYTES,
+    )(jnp.reshape(h_slots, (n,)), u_slots)
 
 
 def pallas_gravity_batched_body_h(ghost: int, subgrid: int,
-                                  g_const: float = 1.0, n_iter: int = 8,
-                                  interpret: bool = True):
+                                  g_const: float = 1.0, n_iter: int = 8):
     """Pallas-backed drop-in for :func:`gravity_batched_body` (same
     ``(u_slots, h_slots)`` calling convention) — registers as the gravity
     family's aggregation-region body on real TPU."""
     def batched(u_slots, h_slots):
         return gravity_pallas(u_slots, h_slots, ghost=ghost, subgrid=subgrid,
-                              g_const=g_const, n_iter=n_iter,
-                              interpret=interpret)
+                              g_const=g_const, n_iter=n_iter)
     return batched

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import pallas_call
+
 
 def _gg_kernel(gl_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int, bc: int):
     ci = pl.program_id(1)
@@ -51,8 +53,7 @@ def _gg_kernel(gl_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int, bc: int):
 
 
 def grouped_gemm(x: jax.Array, w: jax.Array, group_len: jax.Array, *,
-                 bc: int = 128, bn: int = 128, bk: int = 512,
-                 interpret: bool = True) -> jax.Array:
+                 bc: int = 128, bn: int = 128, bk: int = 512) -> jax.Array:
     """x: (E, C, K) @ w: (E, K, N) -> (E, C, N), rows masked by group_len."""
     e, c, k = x.shape
     n = w.shape[2]
@@ -60,7 +61,7 @@ def grouped_gemm(x: jax.Array, w: jax.Array, group_len: jax.Array, *,
     assert c % bc == 0 and n % bn == 0 and k % bk == 0, (x.shape, w.shape)
     n_k = k // bk
     grid = (e, c // bc, n // bn, n_k)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_gg_kernel, n_k=n_k, bc=bc),
         grid=grid,
         in_specs=[
@@ -71,5 +72,4 @@ def grouped_gemm(x: jax.Array, w: jax.Array, group_len: jax.Array, *,
         out_specs=pl.BlockSpec((1, bc, bn), lambda ei, ci, ni, ki: (ei, ci, ni)),
         out_shape=jax.ShapeDtypeStruct((e, c, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bn), jnp.float32)],
-        interpret=interpret,
     )(group_len, x, w)

@@ -22,10 +22,11 @@ Two block layouts are provided:
   lanes.  Aggregation does not just fill the device with blocks, it fills
   the vector unit — the TPU-native reading of "turn fine-grained tasks into
   one larger kernel".  (P=14 is lane-hostile: 14 pads to 128 lanes, wasting
-  9x; slot-lane instead pads T to 8/128 which the bucket sizes match.)
+  9x; slot-lane tiles T by 128 lanes, or takes the whole bucket.)
 
-Validated in interpret mode against ``ref.py`` (the pure-jnp oracle used by
-the production XLA path).
+Compiled by Mosaic on TPU and validated in interpret mode elsewhere
+(``repro.kernels.backend``) against ``ref.py``, the pure-jnp oracle used by
+the production XLA path.
 """
 from __future__ import annotations
 
@@ -35,27 +36,33 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.hydro.euler import N_FIELDS
 from repro.hydro.flux import FACE_QUAD
 from repro.hydro.ppm import DIR_PAIRS
+from repro.kernels.backend import pallas_call
 
 _AXIS_VECS = [(1, 0, 0), (0, 1, 0), (0, 0, 1)]
 
 
 def _shift(u, d: Tuple[int, int, int], k: int, axes: Tuple[int, int, int]):
-    """u(i + k*d) via roll over the given spatial axes."""
-    if k == 0 or d == (0, 0, 0):
-        return u
-    return jnp.roll(u, shift=(-k * d[0], -k * d[1], -k * d[2]), axis=axes)
+    """u(i + k*d) via one roll per spatial axis the shift moves along (a
+    zero shift would lower to a zero-size slice, which Mosaic rejects)."""
+    for dk, ax in zip(d, axes):
+        if k * dk:
+            u = jnp.roll(u, -k * dk, axis=ax)
+    return u
 
 
-def _ppm_side(u, d, side: int, axes):
-    """CW84 limited-parabola surface value toward -d (side=0) or +d (side=1)."""
-    um2 = _shift(u, d, -2, axes)
-    um1 = _shift(u, d, -1, axes)
-    up1 = _shift(u, d, 1, axes)
-    up2 = _shift(u, d, 2, axes)
+def _ppm_limited(at, side: int):
+    """CW84 limited-parabola surface value toward -d (side=0) or +d
+    (side=1), where ``at(k)`` gives u(i + k*d)."""
+    u = at(0)
+    um2 = at(-2)
+    um1 = at(-1)
+    up1 = at(1)
+    up2 = at(2)
     ul = (7.0 / 12.0) * (um1 + u) - (1.0 / 12.0) * (um2 + up1)
     ur = (7.0 / 12.0) * (u + up1) - (1.0 / 12.0) * (um1 + up2)
     extremum = (ur - u) * (u - ul) <= 0.0
@@ -66,6 +73,11 @@ def _ppm_side(u, d, side: int, axes):
     ul = jnp.where(extremum, u, ul_lim)
     ur = jnp.where(extremum, u, ur_lim)
     return ur if side else ul
+
+
+def _ppm_side(u, d, side: int, axes):
+    """:func:`_ppm_limited` on a whole block, shifting by rolls."""
+    return _ppm_limited(lambda k: _shift(u, d, k, axes), side)
 
 
 def _prim(u, gamma):
@@ -102,7 +114,7 @@ def _central_upwind(uL, uR, axis, gamma):
     return jnp.where(span > 1e-12, flux, 0.5 * (fL + fR))
 
 
-def _rhs_field_block(u, h: float, gamma: float, ghost: int, subgrid: int,
+def _rhs_field_block(u, h, gamma: float, ghost: int, subgrid: int,
                      axes: Tuple[int, int, int]):
     """Fused Reconstruct+Flux on one block with field axis 0.
 
@@ -133,9 +145,82 @@ def _rhs_field_block(u, h: float, gamma: float, ghost: int, subgrid: int,
     return acc
 
 
+# -- x-plane form (slot_lane) -----------------------------------------------
+# The same arithmetic, one x-plane (F, P, P, T) at a time: a shift along x
+# picks another plane, shifts along y and z roll the plane.  Interior cells
+# never reach a wrapped value in either form, so both give the same bits.
+
+_PLANE_AXES = (-3, -2)                    # y, z of an x-plane (F, P, P, T)
+
+
+def _plane_at(load, i: int, d, k: int):
+    """u(i + k*d) restricted to x-plane ``i``; ``load(j)`` reads plane j.
+    The x component picks the plane, so ``_shift`` only rolls y and z."""
+    return _shift(load(i + k * d[0]), (0, d[1], d[2]), k,
+                  (0,) + _PLANE_AXES)
+
+
+def _face_plane(load, i, axis: int, gamma: float):
+    """Quadrature-summed flux through the ``axis`` faces of x-plane ``i``
+    (for axis 0, the face between planes i and i+1)."""
+    face = None
+    for (w, pL, sL, pR, sR) in FACE_QUAD[axis]:
+        dL, dR = DIR_PAIRS[pL], DIR_PAIRS[pR]
+        uL = _ppm_limited(lambda k: _plane_at(load, i, dL, k), sL)
+        if axis == 0:                     # the right state is on plane i+1
+            uR = _ppm_limited(lambda k: _plane_at(load, i + 1, dR, k), sR)
+        else:
+            uR = jnp.roll(_ppm_limited(lambda k: _plane_at(load, i, dR, k),
+                                       sR), -1, axis=_PLANE_AXES[axis - 1])
+        f = w * _central_upwind(uL, uR, axis, gamma)
+        face = f if face is None else face + f
+    return face
+
+
+def _rhs_planes(u_ref, out_ref, h, gamma: float, ghost: int, subgrid: int):
+    """:func:`_rhs_field_block` for a lane-major block ``(F, P, P, P, T)``,
+    looping over the interior x-planes.  A whole block of 128 tasks at once
+    would need more VMEM for spills than the chip has, and unrolls into a
+    kernel that takes minutes to compile."""
+    g, s = ghost, subgrid
+    inner = (slice(None), slice(g, g + s), slice(g, g + s))
+    below = (None,
+             (slice(None), slice(g - 1, g - 1 + s), slice(g, g + s)),
+             (slice(None), slice(g, g + s), slice(g - 1, g - 1 + s)))
+
+    def load(j):
+        return u_ref[:, j]
+
+    def plane(i, prev_face):
+        face0 = _face_plane(load, i, 0, gamma)
+
+        @pl.when(i >= g)
+        def _():
+            acc = -((face0[inner] - prev_face[inner]) / h)
+            for axis in (1, 2):
+                face = _face_plane(load, i, axis, gamma)
+                acc = acc - (face[inner] - face[below[axis]]) / h
+            out_ref[:, i - g] = acc
+        return face0
+
+    f, p, t = u_ref.shape[0], u_ref.shape[2], u_ref.shape[-1]
+    jax.lax.fori_loop(g - 1, g + s, plane,
+                      jnp.zeros((f, p, p, t), u_ref.dtype))
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernels
 # ---------------------------------------------------------------------------
+
+# Scoped-VMEM limits.  v5e has 128 MiB of VMEM and Mosaic's default scoped
+# limit is 16 MiB.  Needs at 8^3 sub-grids, compiled for v5e: slot_grid
+# 47 MB (static h) / 29.5 MB (traced h), slot_lane 9.3 MB for one 128-task
+# tile and 17.8 MB for a 256-task bucket (double-buffered tiles).  A 16^3
+# sub-grid needs 130.6 MB in slot_grid, more than the chip has.
+SLOT_GRID_VMEM_BYTES = 64 << 20
+SLOT_LANE_VMEM_BYTES = 64 << 20
+LANES = 128
+
 
 def _kernel_slot_grid(u_ref, out_ref, *, h, gamma, ghost, subgrid):
     u = u_ref[0]                                  # (F, P, P, P)
@@ -143,108 +228,94 @@ def _kernel_slot_grid(u_ref, out_ref, *, h, gamma, ghost, subgrid):
                                   axes=(-3, -2, -1))
 
 
-def _kernel_slot_grid_h(u_ref, h_ref, out_ref, *, gamma, ghost, subgrid):
-    """Per-slot traced cell width: h rides in as a (1, 1) block per task."""
+def _kernel_slot_grid_h(h_ref, u_ref, out_ref, *, gamma, ghost, subgrid):
+    """Per-slot traced cell width: the widths are scalar-prefetched into
+    SMEM and each grid step reads its own."""
     u = u_ref[0]                                  # (F, P, P, P)
-    h = h_ref[0, 0]
+    h = h_ref[pl.program_id(0)]
     out_ref[0] = _rhs_field_block(u, h, gamma, ghost, subgrid,
                                   axes=(-3, -2, -1))
 
 
 def _kernel_slot_lane(u_ref, out_ref, *, h, gamma, ghost, subgrid):
-    u = u_ref[...]                                # (F, P, P, P, T)
-    out_ref[...] = _rhs_field_block(u, h, gamma, ghost, subgrid,
-                                    axes=(-4, -3, -2))
+    _rhs_planes(u_ref, out_ref, h, gamma, ghost, subgrid)
 
 
 def _kernel_slot_lane_h(u_ref, h_ref, out_ref, *, gamma, ghost, subgrid):
-    u = u_ref[...]                                # (F, P, P, P, T)
-    h = h_ref[...][:, 0]                          # (T,) broadcasts over lanes
-    out_ref[...] = _rhs_field_block(u, h, gamma, ghost, subgrid,
-                                    axes=(-4, -3, -2))
+    # h_ref holds (1, T) widths, which broadcast over the lanes
+    _rhs_planes(u_ref, out_ref, h_ref[...], gamma, ghost, subgrid)
 
 
 def hydro_rhs_pallas(u_slots: jax.Array, *, h: Optional[float] = None,
                      h_slots: Optional[jax.Array] = None, gamma: float,
-                     ghost: int, subgrid: int, layout: str = "slot_grid",
-                     lane_tile: int = 8, interpret: bool = True) -> jax.Array:
+                     ghost: int, subgrid: int,
+                     layout: str = "slot_grid") -> jax.Array:
     """Aggregated RHS kernel: (slots, F, P, P, P) -> (slots, F, S, S, S).
 
     Cell width comes in one of two forms:
 
     * ``h``       — a python float baked into the program (uniform grid);
     * ``h_slots`` — a traced ``(slots,)`` array, one width per aggregated
-      task, staged through SMEM-shaped ``(1, 1)`` blocks.  This is the
-      multi-level mode: one compiled kernel serves every refinement level
-      whose sub-grid shapes agree (matching the XLA path's traced-h bodies).
+      task (scalar-prefetched into SMEM for ``slot_grid``, one lane-row
+      block per tile for ``slot_lane``).  This is the multi-level mode: one
+      compiled kernel serves every refinement level whose sub-grid shapes
+      agree (matching the XLA path's traced-h bodies).
     """
     if (h is None) == (h_slots is None):
         raise ValueError("pass exactly one of h / h_slots")
     n, f, p = u_slots.shape[0], u_slots.shape[1], u_slots.shape[2]
     s = subgrid
     kw = dict(gamma=gamma, ghost=ghost, subgrid=subgrid)
-    if h_slots is not None:
-        h2d = jnp.reshape(h_slots, (n, 1))
+    out_grid = jax.ShapeDtypeStruct((n, f, s, s, s), u_slots.dtype)
 
     if layout == "slot_grid":
         if h_slots is None:
-            return pl.pallas_call(
+            return pallas_call(
                 functools.partial(_kernel_slot_grid, h=h, **kw),
                 grid=(n,),
                 in_specs=[pl.BlockSpec((1, f, p, p, p),
                                        lambda i: (i, 0, 0, 0, 0))],
                 out_specs=pl.BlockSpec((1, f, s, s, s),
                                        lambda i: (i, 0, 0, 0, 0)),
-                out_shape=jax.ShapeDtypeStruct((n, f, s, s, s),
-                                               u_slots.dtype),
-                interpret=interpret,
+                out_shape=out_grid,
+                vmem_limit_bytes=SLOT_GRID_VMEM_BYTES,
             )(u_slots)
-        return pl.pallas_call(
+        return pallas_call(
             functools.partial(_kernel_slot_grid_h, **kw),
-            grid=(n,),
-            in_specs=[pl.BlockSpec((1, f, p, p, p),
-                                   lambda i: (i, 0, 0, 0, 0)),
-                      pl.BlockSpec((1, 1), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((1, f, s, s, s),
-                                   lambda i: (i, 0, 0, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((n, f, s, s, s), u_slots.dtype),
-            interpret=interpret,
-        )(u_slots, h2d)
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n,),
+                in_specs=[pl.BlockSpec((1, f, p, p, p),
+                                       lambda i, h_ref: (i, 0, 0, 0, 0))],
+                out_specs=pl.BlockSpec((1, f, s, s, s),
+                                       lambda i, h_ref: (i, 0, 0, 0, 0))),
+            out_shape=out_grid,
+            vmem_limit_bytes=SLOT_GRID_VMEM_BYTES,
+        )(jnp.reshape(h_slots, (n,)), u_slots)
 
     if layout == "slot_lane":
-        # tasks on the minor (lane) axis: (F, P, P, P, slots).  The tile
-        # must divide the bucket; auto-tuned ladders produce non-power-of-
-        # two buckets (DESIGN.md §9), so degrade the tile instead of
-        # asserting — lane utilization drops, correctness does not.
-        t = min(lane_tile, n)
-        while n % t:
-            t -= 1
+        # tasks on the minor (lane) axis: (F, P, P, P, slots), tiled by a
+        # full 128-lane row where the bucket divides into them, else taken
+        # whole (a block's minor dimension must be a multiple of 128 or
+        # the whole array dimension)
+        t = LANES if n % LANES == 0 else n
         u_t = u_slots.transpose(1, 2, 3, 4, 0)
+        in_specs = [pl.BlockSpec((f, p, p, p, t), lambda i: (0, 0, 0, 0, i))]
+        lane = dict(
+            grid=(n // t,),
+            out_specs=pl.BlockSpec((f, s, s, s, t),
+                                   lambda i: (0, 0, 0, 0, i)),
+            out_shape=jax.ShapeDtypeStruct((f, s, s, s, n), u_slots.dtype),
+            vmem_limit_bytes=SLOT_LANE_VMEM_BYTES)
         if h_slots is None:
-            out = pl.pallas_call(
+            out = pallas_call(
                 functools.partial(_kernel_slot_lane, h=h, **kw),
-                grid=(n // t,),
-                in_specs=[pl.BlockSpec((f, p, p, p, t),
-                                       lambda i: (0, 0, 0, 0, i))],
-                out_specs=pl.BlockSpec((f, s, s, s, t),
-                                       lambda i: (0, 0, 0, 0, i)),
-                out_shape=jax.ShapeDtypeStruct((f, s, s, s, n),
-                                               u_slots.dtype),
-                interpret=interpret,
-            )(u_t)
+                in_specs=in_specs, **lane)(u_t)
         else:
-            out = pl.pallas_call(
+            out = pallas_call(
                 functools.partial(_kernel_slot_lane_h, **kw),
-                grid=(n // t,),
-                in_specs=[pl.BlockSpec((f, p, p, p, t),
-                                       lambda i: (0, 0, 0, 0, i)),
-                          pl.BlockSpec((t, 1), lambda i: (i, 0))],
-                out_specs=pl.BlockSpec((f, s, s, s, t),
-                                       lambda i: (0, 0, 0, 0, i)),
-                out_shape=jax.ShapeDtypeStruct((f, s, s, s, n),
-                                               u_slots.dtype),
-                interpret=interpret,
-            )(u_t, h2d)
+                in_specs=in_specs + [pl.BlockSpec((1, t), lambda i: (0, i))],
+                **lane)(u_t, jnp.reshape(h_slots, (1, n)))
         return out.transpose(4, 0, 1, 2, 3)
 
     raise ValueError(f"unknown layout {layout!r}")
@@ -254,8 +325,7 @@ def hydro_rhs_pallas(u_slots: jax.Array, *, h: Optional[float] = None,
 
 def hydro_rhs_pallas_prefix(ring: jax.Array, start, bucket: int, *,
                             h: float, gamma: float, ghost: int, subgrid: int,
-                            layout: str = "slot_grid",
-                            interpret: bool = True) -> jax.Array:
+                            layout: str = "slot_grid") -> jax.Array:
     """Run the aggregated kernel on a slot-ring prefix, staging-free.
 
     ``ring`` is the AggregationExecutor's device-resident staging ring
@@ -266,12 +336,10 @@ def hydro_rhs_pallas_prefix(ring: jax.Array, start, bucket: int, *,
     """
     u = jax.lax.dynamic_slice_in_dim(ring, start, bucket, axis=0)
     return hydro_rhs_pallas(u, h=h, gamma=gamma, ghost=ghost,
-                            subgrid=subgrid, layout=layout,
-                            interpret=interpret)
+                            subgrid=subgrid, layout=layout)
 
 
-def pallas_batched_body(cfg, h: float, layout: str = "slot_grid",
-                        interpret: bool = True):
+def pallas_batched_body(cfg, h: float, layout: str = "slot_grid"):
     """Factory: a batched task body backed by the Pallas kernel, drop-in for
     ``UniformSedovScenario(batched_body=...)`` / ``AggregationExecutor`` —
     the path that runs the paper's GPU kernels through the slot-ring
@@ -279,20 +347,19 @@ def pallas_batched_body(cfg, h: float, layout: str = "slot_grid",
     def batched(u_slots):
         return hydro_rhs_pallas(u_slots, h=h, gamma=cfg.gamma,
                                 ghost=cfg.ghost, subgrid=cfg.subgrid,
-                                layout=layout, interpret=interpret)
+                                layout=layout)
     return batched
 
 
 def pallas_batched_body_h(gamma: float, ghost: int, subgrid: int,
-                          layout: str = "slot_grid", interpret: bool = True):
+                          layout: str = "slot_grid"):
     """Traced-h twin of :func:`pallas_batched_body`: signature
     ``(u_slots, h_slots) -> out_slots``, drop-in as a multi-level
     aggregation-region body (matches ``repro.hydro.stepper
     .level_batched_body``'s calling convention, Pallas-backed)."""
     def batched(u_slots, h_slots):
         return hydro_rhs_pallas(u_slots, h_slots=h_slots, gamma=gamma,
-                                ghost=ghost, subgrid=subgrid,
-                                layout=layout, interpret=interpret)
+                                ghost=ghost, subgrid=subgrid, layout=layout)
     return batched
 
 
@@ -308,11 +375,11 @@ def _kernel_reconstruct(u_ref, out_ref, *, axes=(-3, -2, -1)):
     out_ref[0] = jnp.stack(outs)
 
 
-def hydro_reconstruct_pallas(u_slots: jax.Array, *, interpret: bool = True):
+def hydro_reconstruct_pallas(u_slots: jax.Array):
     """(slots, F, P, P, P) -> (slots, 13, 2, F, P, P, P)."""
     n, f, p = u_slots.shape[0], u_slots.shape[1], u_slots.shape[2]
     npairs = len(DIR_PAIRS)
-    return pl.pallas_call(
+    return pallas_call(
         _kernel_reconstruct,
         grid=(n,),
         in_specs=[pl.BlockSpec((1, f, p, p, p), lambda i: (i, 0, 0, 0, 0))],
@@ -320,7 +387,6 @@ def hydro_reconstruct_pallas(u_slots: jax.Array, *, interpret: bool = True):
                                lambda i: (i, 0, 0, 0, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, npairs, 2, f, p, p, p),
                                        u_slots.dtype),
-        interpret=interpret,
     )(u_slots)
 
 
@@ -348,11 +414,11 @@ def _kernel_flux(recon_ref, out_ref, *, h, gamma, ghost, subgrid):
 
 
 def hydro_flux_pallas(recon: jax.Array, *, h: float, gamma: float,
-                      ghost: int, subgrid: int, interpret: bool = True):
+                      ghost: int, subgrid: int):
     """(slots, 13, 2, F, P, P, P) -> (slots, F, S, S, S)."""
     n, npairs, _, f, p = recon.shape[:5]
     s = subgrid
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel_flux, h=h, gamma=gamma, ghost=ghost,
                           subgrid=subgrid),
         grid=(n,),
@@ -360,5 +426,4 @@ def hydro_flux_pallas(recon: jax.Array, *, h: float, gamma: float,
                                lambda i: (i, 0, 0, 0, 0, 0, 0))],
         out_specs=pl.BlockSpec((1, f, s, s, s), lambda i: (i, 0, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, f, s, s, s), recon.dtype),
-        interpret=interpret,
     )(recon)

@@ -1,4 +1,7 @@
 import os
+# a dry run compiles for 512 virtual host devices and never needs a chip:
+# pin it to the CPU so that on a TPU host it leaves the chip to others
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

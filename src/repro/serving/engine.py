@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import AggregationConfig
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.faults import FaultInjector, poison_slots
 from repro.core.tunestore import TuneStore
 from repro.data.pipeline import length_bucket
@@ -102,8 +103,9 @@ class ServingEngine:
         # cache at it so a restarted server's bucket compiles (and the
         # prefill programs) are disk hits instead of fresh XLA runs
         self._store = TuneStore.open(getattr(self.agg, "tune_store", None))
-        warm = (self._store.enable_compilation_cache()
-                if self._store is not None else False)
+        warm = self._store is not None
+        if warm:
+            enable_compile_cache()
         self.buckets = tuple(b for b in self.agg.bucket_sizes()
                              if b <= max_batch) or (max_batch,)
 

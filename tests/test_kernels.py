@@ -61,13 +61,13 @@ def test_hydro_rhs_kernel_traced_h(layout):
     # widths ALTERNATE so every lane tile is width-heterogeneous (a kernel
     # that collapsed h to one scalar per block would fail, not pass)
     hs = jnp.where(jnp.arange(8) % 2 == 0, 0.02, 0.01).astype(u.dtype)
-    mixed = hydro_rhs_pallas(u, h_slots=hs, layout=layout, lane_tile=4, **kw)
+    mixed = hydro_rhs_pallas(u, h_slots=hs, layout=layout, **kw)
     for i in range(8):
         one = hydro_rhs_pallas(u[i:i + 1], h_slots=hs[i:i + 1],
-                               layout=layout, lane_tile=1, **kw)
+                               layout=layout, **kw)
         np.testing.assert_array_equal(np.asarray(mixed[i:i + 1]),
                                       np.asarray(one))
-    static = hydro_rhs_pallas(u, h=0.01, layout=layout, lane_tile=4, **kw)
+    static = hydro_rhs_pallas(u, h=0.01, layout=layout, **kw)
     scale = float(jnp.max(jnp.abs(static)))
     np.testing.assert_allclose(np.asarray(mixed[1::2]),
                                np.asarray(static[1::2]),

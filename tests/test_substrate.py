@@ -76,15 +76,14 @@ def test_int8_roundtrip_error_bounded():
 
 def test_compressed_allreduce_error_feedback():
     """Across steps, error feedback keeps the accumulated bias near zero."""
-    from jax.experimental.shard_map import shard_map
     mesh = jax.make_mesh((1,), ("data",))
     from repro.optim.compression import compressed_allreduce
 
     def step(g, res):
-        return shard_map(
+        return jax.shard_map(
             lambda g, r: compressed_allreduce(g, "data", r),
             mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False)(g, res)
+            check_vma=False)(g, res)
 
     g = jax.random.normal(jax.random.PRNGKey(1), (64,))
     res = jnp.zeros_like(g)

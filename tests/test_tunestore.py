@@ -39,8 +39,8 @@ from repro.core.aggregation import (
     BucketCostModel, _backend_key, greedy_decomposition,
 )
 from repro.core.tunestore import (
-    SCHEMA_VERSION, RooflinePrior, TuneStore, TuneStoreWarning, code_salt,
-    device_peaks, entry_key,
+    DEVICE_PEAKS, SCHEMA_VERSION, RooflinePrior, TuneStore, TuneStoreWarning,
+    code_salt, device_peaks, entry_key,
 )
 
 WM = 10 ** 9
@@ -280,9 +280,10 @@ def test_stored_entry_for_other_device_is_invisible(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_device_peaks_and_prior_shape():
-    bw, flops, launch = device_peaks(("cpu", "cpu0"))
+    bw, flops, launch = device_peaks(("cpu", "cpu"))
+    assert (bw, flops, launch) == DEVICE_PEAKS["cpu"]     # table, no calibration
     assert bw > 0 and flops > 0 and launch > 0
-    prior = RooflinePrior(("cpu", "cpu0"))
+    prior = RooflinePrior(("cpu", "cpu"))
     specs = (jax.ShapeDtypeStruct((2,), jnp.float32),)
     fn = jax.vmap(_affine)
     t1, t8, t16 = (prior.predict(fn, specs, b) for b in (1, 8, 16))
