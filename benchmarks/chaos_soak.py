@@ -242,7 +242,6 @@ def resume_segment(workdir: str, levels: int, schedule: str) -> dict:
     cfg = HydroConfig(subgrid=8, ghost=3, levels=levels)
     st = sedov_init(cfg)
     dt = courant_dt(st.u, cfg)
-    n_steps, kill_at = N_STEPS, KILL_AT
     ckpt_dir = os.path.join(workdir, "ckpt")
 
     # resume in THIS process, replaying the same fault schedule (faults
@@ -256,7 +255,7 @@ def resume_segment(workdir: str, levels: int, schedule: str) -> dict:
     jax.block_until_ready(final)
     wall = time.perf_counter() - t0
     recovery = int(runner.stats["recovery_steps"])
-    assert recovery == n_steps - kill_at
+    assert recovery == N_STEPS - KILL_AT
     assert warm_start(runner), \
         "resumed process did not warm-start from the tune store"
 
@@ -267,13 +266,13 @@ def resume_segment(workdir: str, levels: int, schedule: str) -> dict:
         AggregationConfig(strategy="s3", n_executors=1, staging="device",
                           max_aggregated=cfg.n_subgrids,
                           launch_watermark=WM))
-    ref = ref_runner.run(st.u, dt, n_steps)
+    ref = ref_runner.run(st.u, dt, N_STEPS)
     np.testing.assert_array_equal(np.asarray(final), np.asarray(ref))
     exe_stats = runner.executor.stats
     fam_faults = {f"trajectory:{fam}": dict(s["faults"])
                   for fam, s in exe_stats["regions"].items()}
     timeouts = sum(f["timeouts"] for f in fam_faults.values())
-    print(f"  kill+resume: killed at step {kill_at}/{n_steps}, resumed "
+    print(f"  kill+resume: killed at step {KILL_AT}/{N_STEPS}, resumed "
           f"{recovery} steps (warm start), bit-identical to uninterrupted "
           f"run; watchdog timeouts={timeouts}")
     return {

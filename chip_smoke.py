@@ -121,7 +121,7 @@ def run_strategy(name: str, cfg, agg, u0, dt, device):
     print(f"  {name}: compile+warm {compile_s:.2f} s, {ms:.2f} ms/step, "
           f"{launches:g} launches/step, peak HBM "
           f"{peak_hbm_bytes(device)} B")
-    return u, runner
+    return u
 
 
 def initial_state(cfg):
@@ -137,12 +137,12 @@ def phase_a(cfg, device) -> None:
     print(f"phase A: {cfg.n_subgrids} sub-grids of {cfg.subgrid}^3 "
           f"({cfg.cells_total} cells), {STEPS} RK3 steps")
     u0, dt = initial_state(cfg)
-    ref, _ = run_strategy("fused", cfg, AggregationConfig(strategy="fused"),
-                          u0, dt, device)
+    ref = run_strategy("fused", cfg, AggregationConfig(strategy="fused"),
+                       u0, dt, device)
     for strategy in ("s3", "mixed"):
-        out, _ = run_strategy(strategy, cfg,
-                              AggregationConfig(strategy=strategy), u0, dt,
-                              device)
+        out = run_strategy(strategy, cfg,
+                           AggregationConfig(strategy=strategy), u0, dt,
+                           device)
         print(f"  {strategy} vs fused: {compare(strategy, out, ref)}")
 
 
@@ -154,13 +154,13 @@ def phase_b(cfg, device) -> None:
     print(f"phase B: {cfg.n_subgrids} sub-grids of {cfg.subgrid}^3 "
           f"({cfg.cells_total} cells), {STEPS} RK3 steps")
     u0, dt = initial_state(cfg)
-    out, _ = run_strategy("s3", cfg, AggregationConfig(strategy="s3"), u0,
-                          dt, device)
+    out = run_strategy("s3", cfg, AggregationConfig(strategy="s3"), u0,
+                       dt, device)
     n = cfg.n_subgrids
     chunked = AggregationConfig(strategy="s3", max_aggregated=n,
                                 buckets=(1, n), inner_chunk=32)
-    ref, _ = run_strategy("s3 one wave in chunks of 32", cfg, chunked, u0,
-                          dt, device)
+    ref = run_strategy("s3 one wave in chunks of 32", cfg, chunked, u0,
+                       dt, device)
     print(f"  s3 vs chunked reference: {compare('s3', out, ref)}")
     del out, ref
     from repro.core import StrategyRunner, UniformSedovScenario
@@ -189,14 +189,14 @@ def phase_c(cfg_shard, cfg_tenant, devices) -> None:
     print(f"phase C: s4 over {n_dev} chips, {cfg_shard.n_subgrids} "
           f"sub-grids; {n_dev} tenants of {cfg_tenant.n_subgrids} sub-grids")
     u0, dt = initial_state(cfg_shard)
-    ref, _ = run_strategy("s3 (one chip)", cfg_shard,
-                          AggregationConfig(strategy="s3"), u0, dt,
-                          devices[0])
+    ref = run_strategy("s3 (one chip)", cfg_shard,
+                       AggregationConfig(strategy="s3"), u0, dt,
+                       devices[0])
     # one bucket per shard: s4 unrolls each shard's greedy drain into one
     # program; compiled for a v5e:2x2, 32 unrolled bucket-32 bodies took
     # 423 s on a CPU host, one 1,024-task body ~20 s
     local = cfg_shard.n_subgrids // n_dev
-    out, runner = run_strategy(
+    out = run_strategy(
         "s4", cfg_shard, AggregationConfig(strategy="s4", shard_devices=n_dev,
                                            max_aggregated=local),
         u0, dt, devices[0])
@@ -206,9 +206,9 @@ def phase_c(cfg_shard, cfg_tenant, devices) -> None:
           f"devices")
 
     t0_u, t0_dt = initial_state(cfg_tenant)
-    solo, _ = run_strategy("s3 tenant (one chip)", cfg_tenant,
-                           AggregationConfig(strategy="s3"), t0_u, t0_dt,
-                           devices[0])
+    solo = run_strategy("s3 tenant (one chip)", cfg_tenant,
+                        AggregationConfig(strategy="s3"), t0_u, t0_dt,
+                        devices[0])
     exe = ShardedAggregationExecutor(
         config=AggregationConfig(strategy="s4",
                                  max_aggregated=cfg_tenant.n_subgrids),

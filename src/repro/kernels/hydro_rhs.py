@@ -217,8 +217,7 @@ def _rhs_planes(u_ref, out_ref, h, gamma: float, ghost: int, subgrid: int):
 # 47 MB (static h) / 29.5 MB (traced h), slot_lane 9.3 MB for one 128-task
 # tile and 17.8 MB for a 256-task bucket (double-buffered tiles).  A 16^3
 # sub-grid needs 130.6 MB in slot_grid, more than the chip has.
-SLOT_GRID_VMEM_BYTES = 64 << 20
-SLOT_LANE_VMEM_BYTES = 64 << 20
+VMEM_LIMIT_BYTES = 64 << 20
 LANES = 128
 
 
@@ -278,7 +277,7 @@ def hydro_rhs_pallas(u_slots: jax.Array, *, h: Optional[float] = None,
                 out_specs=pl.BlockSpec((1, f, s, s, s),
                                        lambda i: (i, 0, 0, 0, 0)),
                 out_shape=out_grid,
-                vmem_limit_bytes=SLOT_GRID_VMEM_BYTES,
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
             )(u_slots)
         return pallas_call(
             functools.partial(_kernel_slot_grid_h, **kw),
@@ -290,7 +289,7 @@ def hydro_rhs_pallas(u_slots: jax.Array, *, h: Optional[float] = None,
                 out_specs=pl.BlockSpec((1, f, s, s, s),
                                        lambda i, h_ref: (i, 0, 0, 0, 0))),
             out_shape=out_grid,
-            vmem_limit_bytes=SLOT_GRID_VMEM_BYTES,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         )(jnp.reshape(h_slots, (n,)), u_slots)
 
     if layout == "slot_lane":
@@ -306,7 +305,7 @@ def hydro_rhs_pallas(u_slots: jax.Array, *, h: Optional[float] = None,
             out_specs=pl.BlockSpec((f, s, s, s, t),
                                    lambda i: (0, 0, 0, 0, i)),
             out_shape=jax.ShapeDtypeStruct((f, s, s, s, n), u_slots.dtype),
-            vmem_limit_bytes=SLOT_LANE_VMEM_BYTES)
+            vmem_limit_bytes=VMEM_LIMIT_BYTES)
         if h_slots is None:
             out = pallas_call(
                 functools.partial(_kernel_slot_lane, h=h, **kw),
