@@ -68,6 +68,7 @@ from repro.core.faults import (
     QuarantineList, RegionFaultError, TaskFailedError, all_finite,
     all_finite_async, poison_slots,
 )
+from repro.core.trace import named, program_name, span
 from repro.core.tunestore import RooflinePrior, TuneStore, TuneStoreWarning
 
 
@@ -445,10 +446,11 @@ class _LaunchRecord:
 
     ``parents`` + ``indices`` are the re-execution recipe: whatever the
     staging mode was, subset ``S`` re-runs as
-    ``region.gather_jit(indices[S], *parents)`` — for ref staging the
-    parents are the submitted parent arrays, for ring staging the launched
-    ring buffers (held by reference, so a post-launch ``swap`` cannot
-    invalidate them), for host staging the stacked input batch itself.
+    ``region.compiled_for(len(S), "gather")(indices[S], *parents)`` — for
+    ref staging the parents are the submitted parent arrays, for ring
+    staging the launched ring buffers (held by reference, so a post-launch
+    ``swap`` cannot invalidate them), for host staging the stacked input
+    batch itself.
     ``poisoned`` records which wave-relative task ids carried an injected
     payload fault at launch time; re-executions re-apply exactly those (the
     poison is a property of the TASK, so bisection converges on it)."""
@@ -456,7 +458,7 @@ class _LaunchRecord:
     region: "_Region"
     out: Any                          # the launch's batched output
     k: int                            # bucket size
-    parents: Tuple[Any, ...]          # arrays gather_jit re-executes against
+    parents: Tuple[Any, ...]          # arrays the gather re-executes against
     indices: List[int]                # per-position absolute parent index
     tasks: List["_Pending"]           # the entries this launch fulfilled
     wave_ids: List[int]               # per-position wave-relative task id
@@ -904,7 +906,7 @@ class _Region:
     """
 
     __slots__ = ("signature", "batched_fn", "ring", "queue", "compiled",
-                 "host_jit", "gather_jit", "stats", "buckets", "chunk",
+                 "stats", "buckets", "chunk",
                  "chunk_tuned", "queued_tasks", "waves", "tuned",
                  "_wave_peak", "_aot_parents", "cost", "_retuned_waves",
                  "_retuned_peak", "_donate", "quarantine", "bad_buckets",
@@ -944,9 +946,6 @@ class _Region:
         self._breaker_wave_mark = 0   # waves counter at last breaker tick
         self._breaker_mark = 0        # cumulative faults at last tick
         self._breaker_open_waves = 0  # waves spent open (cooldown counter)
-        # shared shape-polymorphic wrappers (jit re-specializes per shape,
-        # so ONE wrapper serves every bucket / parent shape)
-        self.reset_compiled()
         self.stats = {"submitted": 0, "launches": 0, "aggregated_hist": {},
                       "queue_hist": {}, "ladder": list(buckets),
                       # warm-start observability (DESIGN.md §13): launches
@@ -982,18 +981,28 @@ class _Region:
 
     # -- compilation cache -------------------------------------------------
     # Each bucket size is a genuinely distinct XLA program (static shapes),
-    # cached under ("ring"|"host"|"prefix", bucket) — plus parent-shape-keyed
-    # AOT entries ("gather"|"prefix_aot", bucket, parent_shapes) installed by
-    # ``AggregationExecutor.warmup(parent_shapes=...)``.
+    # cached under ("ring"|"prefix"|"gather"|"host", bucket) — plus
+    # parent-shape-keyed AOT entries ("gather"|"prefix_aot", bucket,
+    # parent_shapes) installed by ``AggregationExecutor.warmup(
+    # parent_shapes=...)``.
+    def program(self, bucket: int, mode: str) -> Callable:
+        """A new jitted program for one bucket and staging mode, named
+        ``<kernel>_b<bucket>`` (``jit_<kernel>_b<bucket>`` on the device)
+        whatever the mode, so a trace finds each family's device time."""
+        name = program_name(self.signature.kernel, bucket)
+        if mode in ("ring", "prefix"):
+            return jax.jit(named(partial(self._apply_ring_prefix, bucket),
+                                 name))
+        if mode == "gather":
+            return jax.jit(named(self._apply_gathered, name))
+        return jax.jit(named(self._apply_host, name),
+                       donate_argnums=(0,) if self._donate else ())
+
     def compiled_for(self, bucket: int, mode: str = "ring") -> Callable:
         key = (mode, bucket)
         fn = self.compiled.get(key)
         if fn is None:
-            if mode in ("ring", "prefix"):
-                fn = jax.jit(partial(self._apply_ring_prefix, bucket))
-            else:
-                fn = self.host_jit
-            self.compiled[key] = fn
+            fn = self.compiled[key] = self.program(bucket, mode)
         return fn
 
     def ensure_ring(self, capacity: int,
@@ -1019,32 +1028,25 @@ class _Region:
         pk = tuple(tuple(p.shape) for p in parents)
         if ("gather", bucket, pk) not in self.compiled:
             idx = jax.ShapeDtypeStruct((bucket,), jnp.int32)
-            self.compiled[("gather", bucket, pk)] = jax.jit(
-                self._apply_gathered).lower(idx, *parents).compile()
+            self.compiled[("gather", bucket, pk)] = self.program(
+                bucket, "gather").lower(idx, *parents).compile()
         if ("prefix_aot", bucket, pk) not in self.compiled:
             start = jax.ShapeDtypeStruct((), jnp.int32)
-            self.compiled[("prefix_aot", bucket, pk)] = jax.jit(
-                partial(self._apply_ring_prefix, bucket)).lower(
-                    start, *parents).compile()
+            self.compiled[("prefix_aot", bucket, pk)] = self.program(
+                bucket, "prefix").lower(start, *parents).compile()
 
     def aot_ring(self, bucket: int, ring_specs: Sequence[Any]) -> None:
         """Pre-compile the slot-ring prefix program for one bucket."""
         if ("ring", bucket) not in self.compiled:
             start = jax.ShapeDtypeStruct((), jnp.int32)
-            self.compiled[("ring", bucket)] = jax.jit(
-                partial(self._apply_ring_prefix, bucket)).lower(
-                    start, *ring_specs).compile()
+            self.compiled[("ring", bucket)] = self.program(
+                bucket, "ring").lower(start, *ring_specs).compile()
 
     def reset_compiled(self) -> None:
-        """Drop every compiled program AND recreate the shared jit
-        wrappers.  Needed when the inner chunk changes after compilation
-        (a retune-time re-sweep): every cached trace baked the old chunk,
-        and the shared wrappers' per-shape jit caches would silently keep
-        serving it."""
+        """Drop every compiled program and jit wrapper.  Needed when the
+        inner chunk changes after compilation (a retune-time re-sweep):
+        every cached trace baked the old chunk."""
         self.compiled.clear()
-        self.host_jit = jax.jit(self._apply_host,
-                                donate_argnums=(0,) if self._donate else ())
-        self.gather_jit = jax.jit(self._apply_gathered)
 
 
 class AggregationExecutor:
@@ -1375,8 +1377,8 @@ class AggregationExecutor:
                 stacked = tuple(
                     jax.ShapeDtypeStruct((b,) + s.shape, s.dtype)
                     for s in specs)
-                region.compiled[("host", b)] = region.host_jit.lower(
-                    *stacked).compile()
+                region.compiled[("host", b)] = region.program(
+                    b, "host").lower(*stacked).compile()
 
     # -- persistent warm start (DESIGN.md §13) -----------------------------
     def _restore_region(self, region: _Region) -> bool:
@@ -1784,25 +1786,26 @@ class AggregationExecutor:
             region = self._region_for(kernel, args)
             args = tuple(a.parent[a.index] if isinstance(a, SlotView) else a
                          for a in args)
-            t0 = time.perf_counter()
-            ring = region.ensure_ring(self.config.max_aggregated, args)
-            if ring.fill >= ring.capacity:
-                # watermark remainders left a partial prefix consumed; slide
-                # the live tail to the front (one fused device op)
-                first = region.queue[0].slot if region.queue else ring.fill
-                ring.compact(first)
-                for p in region.queue:
-                    p.slot -= first
-            slot = ring.write(args)
-            if self._injector is not None:
-                # ring-corruption site: this task's staged inputs go bad
-                # between submission and launch (bad DMA / stale buffer)
-                bad = self._injector.corrupt_ring(
-                    kernel, region.waves, region._wave_submitted)
-                if bad is not None:
-                    ring.poison(slot, bad)
-            entry = _Pending(future=fut, slot=slot)
-            self.stats["staging_s"] += time.perf_counter() - t0
+            with span("repro.staging", self.stats, "staging_s",
+                      kernel=kernel):
+                ring = region.ensure_ring(self.config.max_aggregated, args)
+                if ring.fill >= ring.capacity:
+                    # watermark remainders left a partial prefix consumed;
+                    # slide the live tail to the front (one fused device op)
+                    first = (region.queue[0].slot if region.queue
+                             else ring.fill)
+                    ring.compact(first)
+                    for p in region.queue:
+                        p.slot -= first
+                slot = ring.write(args)
+                if self._injector is not None:
+                    # ring-corruption site: this task's staged inputs go bad
+                    # between submission and launch (bad DMA / stale buffer)
+                    bad = self._injector.corrupt_ring(
+                        kernel, region.waves, region._wave_submitted)
+                    if bad is not None:
+                        ring.poison(slot, bad)
+                entry = _Pending(future=fut, slot=slot)
         self._enqueue(region, entry)
         return fut
 
@@ -2016,9 +2019,10 @@ class AggregationExecutor:
                mode: str):
         """One bucket's inputs -> (fn, call_args, parents, indices): the
         compiled program plus the §11 re-execution recipe — ``parents`` are
-        the concrete arrays ``region.gather_jit`` can re-run any position
-        subset against (parent set / launched ring buffers / stacked host
-        batch), ``indices`` each position's absolute index into them."""
+        the concrete arrays the region's gather program can re-run any
+        position subset against (parent set / launched ring buffers /
+        stacked host batch), ``indices`` each position's absolute index into
+        them."""
         if mode == "ref":
             indices: List[int] = []
             for t in tasks:
@@ -2039,7 +2043,7 @@ class AggregationExecutor:
             else:
                 idx = jnp.asarray(indices, jnp.int32)
                 fn = (region.compiled.get(("gather", k, pk))
-                      or region.gather_jit)
+                      or region.compiled_for(k, "gather"))
                 call_args = (idx,) + parents
         elif mode == "ring":
             first = tasks[0].slot
@@ -2059,7 +2063,7 @@ class AggregationExecutor:
                     stacked.append(jnp.asarray(self.buffers.stage(parts)))
             parents = tuple(stacked)
             indices = list(range(k))
-            fn = region.compiled.get(("host", k), region.host_jit)
+            fn = region.compiled_for(k, "host")
             call_args = parents
         return fn, call_args, parents, indices
 
@@ -2069,9 +2073,10 @@ class AggregationExecutor:
         futures; under ``guard="finite"`` the launch is also recorded for
         the post-drain audit.  A compile/launch fault degrades the bucket
         (``_degrade``) instead of propagating — the wave survives."""
-        t0 = time.perf_counter()
-        fn, call_args, parents, indices = self._stage(region, tasks, k, mode)
-        self.stats["staging_s"] += time.perf_counter() - t0
+        with span("repro.staging", self.stats, "staging_s",
+                  kernel=region.signature.kernel, bucket=k):
+            fn, call_args, parents, indices = self._stage(region, tasks, k,
+                                                          mode)
         try:
             out = self._dispatch(region, fn, call_args, k)
         except (BucketCompileError, LaunchFaultError,
@@ -2163,7 +2168,8 @@ class AggregationExecutor:
                             raise LaunchFaultError(
                                 f"injected launch failure: kernel {kern!r} "
                                 f"bucket {k}")
-                out = self.pool.get().launch(fn, *call_args, family=kern)
+                out = self.pool.get().launch(fn, *call_args, family=kern,
+                                             bucket=k)
                 if self._launch_timeout:
                     self._watchdog_records.append(
                         (time.monotonic() + self._launch_timeout, out,
@@ -2307,8 +2313,10 @@ class AggregationExecutor:
         results — the no-padding equivalence invariant."""
         region = rec.region
         idx = jnp.asarray([rec.indices[p] for p in grp], jnp.int32)
-        out = self.pool.get().launch(region.gather_jit, idx, *rec.parents,
-                                     family=region.signature.kernel)
+        out = self.pool.get().launch(region.compiled_for(len(grp), "gather"),
+                                     idx, *rec.parents,
+                                     family=region.signature.kernel,
+                                     bucket=len(grp))
         pois = {j: rec.poisoned[rec.wave_ids[p]]
                 for j, p in enumerate(grp)
                 if rec.wave_ids[p] in rec.poisoned}

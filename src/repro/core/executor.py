@@ -20,10 +20,11 @@ insufficient on MI100, which we reproduce on this third runtime.
 from __future__ import annotations
 
 import itertools
-import time
 from typing import Any, Callable, List, Optional, Sequence
 
 import jax
+
+from repro.core.trace import span
 
 
 def _is_ready(x) -> bool:
@@ -54,13 +55,25 @@ class DeviceExecutor:
         self._max_tracked = max_inflight_tracked
         self.launches = 0           # statistics
         self.launches_by_family: dict = {}   # kernel-family tag -> count
-        self.dispatch_s = 0.0       # host time spent enqueueing launches
+        # host time spent enqueueing launches (the ``repro.dispatch`` spans)
+        self.stats = {"dispatch_s": 0.0}
 
-    def launch(self, fn: Callable, *args, family: Optional[str] = None) -> Any:
+    @property
+    def dispatch_s(self) -> float:
+        return self.stats["dispatch_s"]
+
+    @dispatch_s.setter
+    def dispatch_s(self, value: float) -> None:
+        self.stats["dispatch_s"] = value
+
+    def launch(self, fn: Callable, *args, family: Optional[str] = None,
+               bucket: Optional[int] = None) -> Any:
         """Enqueue fn(*args) (async under XLA) and track its outputs.
 
         ``family`` tags the launch with its kernel family (TaskSignature
-        kernel id) so interleaved multi-region dispatch is observable.
+        kernel id) so interleaved multi-region dispatch is observable;
+        ``family`` and ``bucket`` (the tasks the launch carries) are the
+        ``repro.dispatch`` span's metadata.
 
         A raising ``fn`` must leave the executor consistent: the host time
         spent before the raise still lands in ``dispatch_s`` (the overhead
@@ -68,11 +81,9 @@ class DeviceExecutor:
         record launches that actually enqueued — a failed dispatch must
         not make ``busy()``/``drain()`` wait on buffers that don't exist.
         """
-        t0 = time.perf_counter()
-        try:
+        with span("repro.dispatch", self.stats, "dispatch_s", kernel=family,
+                  bucket=bucket):
             out = fn(*args)
-        finally:
-            self.dispatch_s += time.perf_counter() - t0
         self.launches += 1
         if family is not None:
             self.launches_by_family[family] = \

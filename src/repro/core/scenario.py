@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.configs.base import (
     AMRHydroConfig, GravityHydroConfig, HydroConfig,
 )
+from repro.core.trace import named, program_name
 from repro.hydro.state import (
     assemble_global, extract_subgrids, extract_subgrids_multilevel,
     sync_coarse,
@@ -88,7 +89,7 @@ def stage_family(fam: KernelFamily, n_body_args: int) -> KernelFamily:
         out = fam.batched_body(*args[:n_body_args])
         return jax.vmap(fam.epilogue)(out, *args[n_body_args:])
 
-    return KernelFamily(fam.kernel + "+epi", batched, jax.jit(batched))
+    return KernelFamily(fam.kernel + "+epi", batched)
 
 
 def _cached_u0_interiors(scn, u0, v, v_int, extract):
@@ -228,7 +229,8 @@ class Scenario:
 
     def jitted_body(self, kernel: str) -> Callable:
         """The family's jitted batched body (one shared wrapper per family,
-        so reference and fused strategy hit the same compiled programs)."""
+        so reference and fused strategy hit the same compiled programs),
+        named ``jit_<kernel>`` on the device."""
         cache: Dict[str, Callable] = getattr(self, "_jit_cache", None)
         if cache is None:
             cache = {}
@@ -236,7 +238,8 @@ class Scenario:
         fn = cache.get(kernel)
         if fn is None:
             fam = self.family(kernel)
-            fn = fam.jit_body or jax.jit(fam.batched_body)
+            fn = fam.jit_body or jax.jit(named(fam.batched_body,
+                                               program_name(kernel)))
             cache[kernel] = fn
         return fn
 
@@ -518,7 +521,6 @@ class GravityScenario(Scenario):
         self._families = (
             KernelFamily("hydro_rhs",
                          level_batched_body(hc.gamma, hc.ghost, hc.subgrid),
-                         level_batched_jit(hc.gamma, hc.ghost, hc.subgrid),
                          epilogue=rk_stage_epilogue),
             KernelFamily("gravity",
                          gravity_batched_body(hc.ghost, hc.subgrid,

@@ -38,7 +38,6 @@ buckets — per-instance cost drops as tenant count rises (the
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -54,6 +53,7 @@ from repro.core.aggregation import (RangeFuture, TaskFuture, TaskSignature,
                                     greedy_decomposition)
 from repro.core.executor import ExecutorPool
 from repro.core.faults import (FaultInjector, TaskFailedError, poison_slots)
+from repro.core.trace import named, program_name, span
 from repro.distributed.api import DEFAULT_RULES, subgrid_mesh
 
 # the logical axes a task range distributes over (DEFAULT_RULES["subgrid"])
@@ -276,8 +276,9 @@ class ShardedAggregationExecutor:
                 return jax.tree_util.tree_map(
                     lambda *xs: jnp.concatenate(xs), *outs)
 
-            fn = jax.jit(shard_map(local_drain, mesh=self.mesh,
-                                   in_specs=spec, out_specs=spec))
+            fn = jax.jit(named(shard_map(local_drain, mesh=self.mesh,
+                                         in_specs=spec, out_specs=spec),
+                               program_name(region.kernel, local)))
             region.compiled[key] = fn
         return fn
 
@@ -304,7 +305,7 @@ class ShardedAggregationExecutor:
                 return jax.tree_util.tree_map(
                     lambda *xs: jnp.concatenate(xs), *outs)
 
-            fn = jax.jit(drain)
+            fn = jax.jit(named(drain, program_name(region.kernel, count)))
             region.compiled[key] = fn
         return fn
 
@@ -346,14 +347,14 @@ class ShardedAggregationExecutor:
         region.queue.append(_ShardPending(RangeFuture(len(futs)), parents,
                                           0, len(futs), singles=futs))
 
-    def _stage(self, args: Sequence[Any]) -> Tuple[Any, ...]:
-        t0 = time.perf_counter()
-        staged = tuple(
-            a if getattr(a, "sharding", None) == self._sharding
-            else jax.device_put(a, self._sharding)
-            for a in args)
-        self.stats["staging_s"] += time.perf_counter() - t0
-        return staged
+    def _stage(self, args: Sequence[Any], kernel: str,
+               bucket: int) -> Tuple[Any, ...]:
+        with span("repro.staging", self.stats, "staging_s", kernel=kernel,
+                  bucket=bucket):
+            return tuple(
+                a if getattr(a, "sharding", None) == self._sharding
+                else jax.device_put(a, self._sharding)
+                for a in args)
 
     def _dispatch(self, region: _ShardRegion, entry: _ShardPending,
                   occupancy: List[int]) -> List[_ShardLaunch]:
@@ -368,8 +369,9 @@ class ShardedAggregationExecutor:
                                           entry.start + n_even, axis=0)
                 for p in entry.parents)
             out = self.pool.get().launch(
-                self._sharded_fn(region, local, args), *self._stage(args),
-                family=region.kernel)
+                self._sharded_fn(region, local, args),
+                *self._stage(args, region.kernel, local),
+                family=region.kernel, bucket=local)
             recs.append(_ShardLaunch(region, entry, out, 0, n_even,
                                      region.waves))
             hist = region.stats["aggregated_hist"]
@@ -388,7 +390,7 @@ class ShardedAggregationExecutor:
                          for p in entry.parents)
             out = self.pool.get().launch(
                 self._chunked_fn(region, rem, args), *args,
-                family=region.kernel)
+                family=region.kernel, bucket=rem)
             recs.append(_ShardLaunch(region, entry, out, n_even, rem,
                                      region.waves))
             hist = region.stats["aggregated_hist"]

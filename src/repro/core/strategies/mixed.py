@@ -46,6 +46,7 @@ from repro.core.faults import NonFiniteStateError, all_finite, poison_slots
 from repro.core.strategies.base import RunContext, Strategy, register_strategy
 from repro.core.strategies.s2 import S2Strategy
 from repro.core.strategies.s3 import S3Strategy
+from repro.core.trace import span
 
 
 @register_strategy("mixed")
@@ -122,7 +123,8 @@ class MixedStrategy(Strategy):
 
     def _launch_fused(self, scenario, pop, ctx: RunContext):
         out = ctx.pool.get().launch(scenario.jitted_body(pop.kernel),
-                                    *pop.parents, family=pop.kernel)
+                                    *pop.parents, family=pop.kernel,
+                                    bucket=pop.n_tasks)
         ctx.stats["kernel_launches"] += 1
         # stats parity: the same TaskSignature family key the executor and
         # the s2 route use, so BENCH helpers read one key per family
@@ -172,14 +174,19 @@ class MixedStrategy(Strategy):
 
     # -- strategy protocol -------------------------------------------------
     def run_iteration(self, scenario, state, ctx: RunContext):
-        pops = scenario.populations(state)
-        return scenario.assemble(state, self._run_wave(scenario, pops, ctx))
+        with span("repro.populations", scenario=scenario.name):
+            pops = scenario.populations(state)
+        outs = self._run_wave(scenario, pops, ctx)
+        with span("repro.assemble"):
+            return scenario.assemble(state, outs)
 
     def run_stage(self, scenario, u0, v, dt, c0, c1, ctx: RunContext):
         if ctx.config.staging == "host":
             return None                  # baseline path stays per-task
-        pops = scenario.stage_populations(u0, v, dt, c0, c1)
+        with span("repro.populations", scenario=scenario.name):
+            pops = scenario.stage_populations(u0, v, dt, c0, c1)
         if pops is None:
             return None
         outs = self._run_wave(scenario, pops, ctx)
-        return scenario.assemble_stage(v, outs, dt, c0, c1)
+        with span("repro.assemble"):
+            return scenario.assemble_stage(v, outs, dt, c0, c1)

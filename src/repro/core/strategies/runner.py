@@ -34,6 +34,7 @@ from repro.core.scenario import (
     AMRSedovScenario, Scenario, UniformSedovScenario,
 )
 from repro.core.strategies.base import RunContext, get_strategy_class
+from repro.core.trace import span
 
 
 class StrategyRunner:
@@ -45,7 +46,8 @@ class StrategyRunner:
     ``iterations`` / ``staging_s`` accumulate per-call deltas for every
     strategy, and — when an aggregation executor exists — ``regions`` is a
     live view of the per-``TaskSignature``-family bucket histograms.
-    Per-family launch counts are on ``launches_by_family``.
+    Per-family launch counts are on ``launches_by_family``.  Host spans
+    (``repro.step`` down to ``repro.dispatch``) are in ``core/trace.py``.
     """
 
     def __init__(self, scenario: Scenario, agg: AggregationConfig,
@@ -203,20 +205,34 @@ class StrategyRunner:
 
     # -- RK3 (three iterations per time-step, as in the paper) -------------
     def rk3_step(self, state, dt):
-        if self._fuse_epilogue:
-            out = self._rk3_step_fused_stages(state, dt)
-            if out is not None:
-                return out
-        tm = jax.tree_util.tree_map
-        l0 = self.rhs(state)
-        u1 = tm(lambda u, l: u + dt * l, state, l0)
-        l1 = self.rhs(u1)
-        u2 = tm(lambda u, a, l: 0.75 * u + 0.25 * (a + dt * l),
+        with span("repro.step", step=self.stats["iterations"] // 3,
+                  strategy=self.strategy):
+            if self._fuse_epilogue:
+                out = self._rk3_step_fused_stages(state, dt)
+                if out is not None:
+                    return out
+            l0 = self._rhs_stage(0, state)
+            u1 = self._combine(lambda u, l: u + dt * l, state, l0)
+            l1 = self._rhs_stage(1, u1)
+            u2 = self._combine(
+                lambda u, a, l: 0.75 * u + 0.25 * (a + dt * l),
                 state, u1, l1)
-        l2 = self.rhs(u2)
-        out = tm(lambda u, a, l: (1.0 / 3.0) * u + (2.0 / 3.0) * (a + dt * l),
-                 state, u2, l2)
-        return self.scenario.finalize_step(out)
+            l2 = self._rhs_stage(2, u2)
+            out = self._combine(
+                lambda u, a, l: (1.0 / 3.0) * u + (2.0 / 3.0) * (a + dt * l),
+                state, u2, l2)
+            with span("repro.combine"):
+                return self.scenario.finalize_step(out)
+
+    def _rhs_stage(self, stage: int, state):
+        with span("repro.rk_stage", stage=stage):
+            return self.rhs(state)
+
+    @staticmethod
+    def _combine(fn, *trees):
+        """One RK axpy over the state pytrees (eager device ops)."""
+        with span("repro.combine"):
+            return jax.tree_util.tree_map(fn, *trees)
 
     def _rk3_step_fused_stages(self, state, dt):
         """RK3 through the epilogue-fused stage path: each Shu-Osher stage
@@ -224,18 +240,24 @@ class StrategyRunner:
         body and stage axpy in ONE program per bucket (DESIGN.md §9).
         Returns None (falling back to the generic path) when the strategy
         has no ``run_stage``."""
-        stage = self._strategy.run_stage
         sc = self.scenario
-        u1 = stage(sc, state, state, dt, 0.0, 1.0, self.ctx)
+
+        def stage(i, v, c0, c1):
+            with span("repro.rk_stage", stage=i):
+                return self._strategy.run_stage(sc, state, v, dt, c0, c1,
+                                                self.ctx)
+
+        u1 = stage(0, state, 0.0, 1.0)
         if u1 is None:
             self._fuse_epilogue = False       # strategy has no stage path
             return None
         self.stats["iterations"] += 1
-        u2 = stage(sc, state, u1, dt, 0.75, 0.25, self.ctx)
+        u2 = stage(1, u1, 0.75, 0.25)
         self.stats["iterations"] += 1
-        out = stage(sc, state, u2, dt, 1.0 / 3.0, 2.0 / 3.0, self.ctx)
+        out = stage(2, u2, 1.0 / 3.0, 2.0 / 3.0)
         self.stats["iterations"] += 1
-        return sc.finalize_step(out)
+        with span("repro.combine"):
+            return sc.finalize_step(out)
 
     # -- whole-trajectory scan driver (fused upper bound) ------------------
     def _trajectory_impl(self, n_steps: int, state, dt):

@@ -35,6 +35,7 @@ from repro.core.aggregation import (
     s2_width_candidates,
 )
 from repro.core.strategies.base import RunContext, Strategy, register_strategy
+from repro.core.trace import span
 
 
 @register_strategy("s2")
@@ -103,10 +104,12 @@ class S2Strategy(Strategy):
         main = n - n % width
         for i in range(0, main, width):
             ring = ctx.pool.get().launch(scatters[width], ring, jnp.int32(i),
-                                         *pop.parents, family=pop.kernel)
+                                         *pop.parents, family=pop.kernel,
+                                         bucket=width)
         for i in range(main, n):
             ring = ctx.pool.get().launch(scatters[1], ring, jnp.int32(i),
-                                         *pop.parents, family=pop.kernel)
+                                         *pop.parents, family=pop.kernel,
+                                         bucket=1)
         launches = main // width + (n - main)
         ctx.stats["kernel_launches"] += launches
         stats["submitted"] += n
@@ -119,6 +122,8 @@ class S2Strategy(Strategy):
         return ring
 
     def run_iteration(self, scenario, state, ctx: RunContext):
-        outs = [self.launch_population(scenario, pop, ctx)
-                for pop in scenario.populations(state)]
-        return scenario.assemble(state, outs)
+        with span("repro.populations", scenario=scenario.name):
+            pops = scenario.populations(state)
+        outs = [self.launch_population(scenario, pop, ctx) for pop in pops]
+        with span("repro.assemble"):
+            return scenario.assemble(state, outs)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from repro.core.aggregation import gather_futures
 from repro.core.faults import LaunchTimeoutError, TaskFailedError
 from repro.core.strategies.base import RunContext, Strategy, register_strategy
+from repro.core.trace import span
 
 
 @register_strategy("s3", "s2+s3")
@@ -46,38 +47,41 @@ class S3Strategy(Strategy):
 
     def _submit_populations(self, exe, pops, host: bool):
         """One wave: bulk range per population (device staging), round-robin
-        per-task interleave across families (host staging)."""
-        futs = [[] for _ in pops]
-        if not host:
-            # one range entry per population; same-kernel populations stay
-            # contiguous by construction (each range is one entry)
+        per-task interleave across families (host staging).  Launches the
+        submissions trigger nest under the ``repro.submit`` span."""
+        with span("repro.submit"):
+            futs = [[] for _ in pops]
+            if not host:
+                # one range entry per population; same-kernel populations stay
+                # contiguous by construction (each range is one entry)
+                for pi, pop in enumerate(pops):
+                    if pop.n_tasks:
+                        futs[pi].append(pop.submit_to(exe))
+                return futs
+            # flatten each kernel family's populations into one ordered task
+            # list, then round-robin one submission per family per turn
+            lanes = {}
             for pi, pop in enumerate(pops):
-                if pop.n_tasks:
-                    futs[pi].append(pop.submit_to(exe))
+                lanes.setdefault(pop.kernel, []).extend(
+                    (pi, pop, i) for i in range(pop.n_tasks))
+            cursors = [iter(lane) for lane in lanes.values()]
+            while cursors:
+                live = []
+                for cur in cursors:                   # interleave the families
+                    nxt = next(cur, None)
+                    if nxt is None:
+                        continue
+                    pi, pop, i = nxt
+                    futs[pi].append(exe.submit(
+                        *(par[i] for par in pop.parents), kernel=pop.kernel))
+                    live.append(cur)
+                cursors = live
             return futs
-        # flatten each kernel family's populations into one ordered task
-        # list, then round-robin one submission per family per turn
-        lanes = {}
-        for pi, pop in enumerate(pops):
-            lanes.setdefault(pop.kernel, []).extend(
-                (pi, pop, i) for i in range(pop.n_tasks))
-        cursors = [iter(lane) for lane in lanes.values()]
-        while cursors:
-            live = []
-            for cur in cursors:                   # interleave the families
-                nxt = next(cur, None)
-                if nxt is None:
-                    continue
-                pi, pop, i = nxt
-                futs[pi].append(exe.submit(
-                    *(par[i] for par in pop.parents), kernel=pop.kernel))
-                live.append(cur)
-            cursors = live
-        return futs
 
     def _drain(self, scenario, exe, pops, futs):
         try:
-            exe.flush()
+            with span("repro.flush"):
+                exe.flush()
         except LaunchTimeoutError as err:
             # the flush-time watchdog caught a REAL hang (DESIGN.md §14):
             # name the wave's families so the timeout is attributable —
@@ -91,31 +95,36 @@ class S3Strategy(Strategy):
         # task structure, e.g. a refinement level with no patches): hand
         # assemble a zero-length batch instead of gathering nothing
         outs = []
-        for pop, f in zip(pops, futs):
-            if f:
-                try:
-                    outs.append(gather_futures(f))
-                except TaskFailedError as err:
-                    # translate the executor's wave-relative task ids into
-                    # the scenario's own vocabulary before propagating —
-                    # the physicist debugging a tripped wave should read
-                    # "subgrid (i, j)", not a slot number (DESIGN.md §11)
-                    what = ", ".join(
-                        scenario.describe_task(pop.kernel, tid)
-                        for tid in err.task_ids) or "unknown task"
-                    raise TaskFailedError(
-                        f"{what} failed during aggregated execution: {err}",
-                        task_ids=err.task_ids,
-                        kernel=pop.kernel) from err
-            else:
-                spec = jax.eval_shape(
-                    scenario.family(pop.kernel).batched_body, *pop.parents)
-                outs.append(jnp.zeros(spec.shape, spec.dtype))
+        with span("repro.gather"):
+            for pop, f in zip(pops, futs):
+                if f:
+                    try:
+                        outs.append(gather_futures(f))
+                    except TaskFailedError as err:
+                        # translate the executor's wave-relative task ids
+                        # into the scenario's own vocabulary before
+                        # propagating — the physicist debugging a tripped
+                        # wave should read "subgrid (i, j)", not a slot
+                        # number (DESIGN.md §11)
+                        what = ", ".join(
+                            scenario.describe_task(pop.kernel, tid)
+                            for tid in err.task_ids) or "unknown task"
+                        raise TaskFailedError(
+                            f"{what} failed during aggregated execution: "
+                            f"{err}",
+                            task_ids=err.task_ids,
+                            kernel=pop.kernel) from err
+                else:
+                    spec = jax.eval_shape(
+                        scenario.family(pop.kernel).batched_body,
+                        *pop.parents)
+                    outs.append(jnp.zeros(spec.shape, spec.dtype))
         return outs
 
     def run_iteration(self, scenario, state, ctx: RunContext):
         exe = ctx.executor
-        pops = scenario.populations(state)
+        with span("repro.populations", scenario=scenario.name):
+            pops = scenario.populations(state)
         before_launches = exe.stats["launches"]
         before_staging = exe.stats["staging_s"]
         futs = self._submit_populations(exe, pops,
@@ -124,12 +133,14 @@ class S3Strategy(Strategy):
         ctx.stats["staging_s"] += exe.stats["staging_s"] - before_staging
         ctx.stats["kernel_launches"] += (exe.stats["launches"]
                                          - before_launches)
-        return scenario.assemble(state, outs)
+        with span("repro.assemble"):
+            return scenario.assemble(state, outs)
 
     def run_stage(self, scenario, u0, v, dt, c0, c1, ctx: RunContext):
         if ctx.config.staging == "host":
             return None                  # baseline path stays per-task
-        pops = scenario.stage_populations(u0, v, dt, c0, c1)
+        with span("repro.populations", scenario=scenario.name):
+            pops = scenario.stage_populations(u0, v, dt, c0, c1)
         if pops is None:
             return None
         exe = ctx.executor
@@ -140,4 +151,5 @@ class S3Strategy(Strategy):
         ctx.stats["staging_s"] += exe.stats["staging_s"] - before_staging
         ctx.stats["kernel_launches"] += (exe.stats["launches"]
                                          - before_launches)
-        return scenario.assemble_stage(v, outs, dt, c0, c1)
+        with span("repro.assemble"):
+            return scenario.assemble_stage(v, outs, dt, c0, c1)

@@ -45,10 +45,13 @@ def level_batched_body(gamma: float, ghost: int, subgrid: int):
     """The shape-polymorphic aggregation-region body for one sub-grid size:
     ``(k, F, P, P, P), (k,) -> (k, F, S, S, S)`` with per-task traced h.
     Cached so every runner / reference sharing (gamma, ghost, subgrid) gets
-    the SAME callable — and therefore the same compiled programs."""
+    the SAME callable — and therefore the same compiled programs.  Named
+    after the AMR family it serves, so its jitted twin compiles to
+    ``jit_hydro_rhs_s<subgrid>``."""
     def body(u_padded, h):
         return subgrid_rhs(u_padded, h, gamma=gamma, ghost=ghost,
                            subgrid=subgrid)
+    body.__name__ = f"hydro_rhs_s{subgrid}"
     return jax.vmap(body)
 
 

@@ -113,11 +113,12 @@ def gravity_batched_body(ghost: int, subgrid: int, g_const: float = 1.0,
     """The aggregation-region body: ``(k, F, P, P, P), (k,) -> (k, 4, S, S,
     S)`` with per-task traced h.  Cached so every runner / reference
     sharing the parameters gets the SAME callable (and compiled programs),
-    mirroring ``repro.hydro.stepper.level_batched_body``."""
-    def body(u_padded, h):
+    mirroring ``repro.hydro.stepper.level_batched_body``; its jitted twin
+    compiles to ``jit_gravity``."""
+    def gravity(u_padded, h):
         return subgrid_gravity(u_padded, h, ghost=ghost, subgrid=subgrid,
                                g_const=g_const, n_iter=n_iter)
-    return jax.vmap(body)
+    return jax.vmap(gravity)
 
 
 @lru_cache(maxsize=None)
