@@ -46,12 +46,30 @@ from repro.hydro.stepper import (
 from repro.kernels.gravity import (
     gravity_batched_body, gravity_batched_jit, gravity_source_update,
 )
+from repro.kernels.hydro_rhs import pallas_batched_body
 
 
 def xla_task_body(cfg: HydroConfig, h: float) -> Callable:
     """The fine-grained hydro task body: (F, P, P, P) -> (F, S, S, S)."""
     return partial(subgrid_rhs, h=h, gamma=cfg.gamma,
                    ghost=cfg.ghost, subgrid=cfg.subgrid)
+
+
+# (subgrid, ghost, dtype) at which the slot_lane Pallas kernel is measured
+# on a TPU v5e to fit its scoped VMEM (9.3 MB per 128-task tile) and to
+# equal the jnp body bit for bit (at 32, 128 and 512 tasks).
+SLOT_LANE_SHAPE = (8, 3, "float32")
+
+
+def uniform_batched_body(cfg: HydroConfig, h: float,
+                         platform: str) -> Callable:
+    """The batched body a uniform hydro scenario runs on ``platform``: the
+    ``slot_lane`` Pallas kernel on a TPU at a sub-grid shape where it is
+    measured to fit and to match, else the vmapped jnp task body."""
+    if (platform == "tpu" and (cfg.subgrid, cfg.ghost, jnp.dtype(cfg.dtype)
+                               .name) == SLOT_LANE_SHAPE):
+        return pallas_batched_body(cfg, h, "slot_lane")
+    return jax.vmap(xla_task_body(cfg, h))
 
 
 @dataclass(frozen=True)
@@ -259,8 +277,9 @@ class UniformSedovScenario(Scenario):
     """AMR-off Sedov blast: one kernel family, one task per sub-grid.
 
     The cell width is uniform, so it is baked into the body at trace time
-    (the single-level fast path); custom ``body``/``batched_body`` let the
-    Pallas kernels slot in unchanged.
+    (the single-level fast path).  The default batched body is
+    :func:`uniform_batched_body` for the platform of ``jax.devices()[0]``;
+    a custom ``body`` (vmapped) or ``batched_body`` replaces it.
     """
 
     def __init__(self, cfg: HydroConfig, bc: str = "outflow",
@@ -271,7 +290,11 @@ class UniformSedovScenario(Scenario):
         n = cfg.grids_per_edge * cfg.subgrid
         self.h = cfg.domain / n
         self.body = body or xla_task_body(cfg, self.h)
-        self.batched_body = batched_body or jax.vmap(self.body)
+        if batched_body is None:
+            batched_body = (jax.vmap(body) if body is not None else
+                            uniform_batched_body(cfg, self.h,
+                                                 jax.devices()[0].platform))
+        self.batched_body = batched_body
         self.name = cfg.name
         self._dtype = jnp.dtype(cfg.dtype)
         self._families = (KernelFamily("hydro_rhs", self.batched_body,

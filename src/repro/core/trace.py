@@ -12,6 +12,11 @@ the profiler keeps as the event's stats, not in its name.
 ``named`` gives a jitted program a stable name: ``jax.jit`` of a callable
 named ``hydro_rhs_b32`` compiles a module ``jit_hydro_rhs_b32``, which is
 what the device trace calls each of its operations' program.
+
+``kernel_traces`` (kept by ``repro.kernels.backend``) counts, per process
+and kernel name, how often a Pallas kernel body was traced into a jaxpr:
+set-up work that a warm compile cache does not save, so a kernel that is
+traced again for every program that calls it shows here.
 """
 from __future__ import annotations
 
@@ -19,6 +24,8 @@ import time
 from typing import Callable, Dict, Optional
 
 from jax.profiler import TraceAnnotation
+
+from repro.kernels.backend import kernel_traces  # noqa: F401
 
 
 class span:

@@ -22,7 +22,8 @@ Two block layouts are provided:
   lanes.  Aggregation does not just fill the device with blocks, it fills
   the vector unit — the TPU-native reading of "turn fine-grained tasks into
   one larger kernel".  (P=14 is lane-hostile: 14 pads to 128 lanes, wasting
-  9x; slot-lane tiles T by 128 lanes, or takes the whole bucket.)
+  9x; slot-lane takes a bucket of up to 128 tasks whole and pads a larger
+  one to 128-lane tiles.)
 
 Compiled by Mosaic on TPU and validated in interpret mode elsewhere
 (``repro.kernels.backend``) against ``ref.py``, the pure-jnp oracle used by
@@ -35,6 +36,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -45,33 +47,50 @@ from repro.kernels.backend import pallas_call
 
 _AXIS_VECS = [(1, 0, 0), (0, 1, 0), (0, 0, 1)]
 
+# The stencil arithmetic below binds lax primitives directly.  A jnp call
+# (an operator on a tracer included) is a nested ``jit`` that costs far
+# more to trace than the primitive it binds, and one trace of the kernel
+# makes ~7,000 of them.  Each function emits the primitives its jnp
+# spelling would, in the same order, so the kernel computes the same bits.
+
+
+def _roll(u, shift: int, axis: int):
+    """``jnp.roll(u, shift, axis)`` for a static, non-zero shift."""
+    ax, n = axis % u.ndim, u.shape[axis]
+    i = -shift % n
+    return lax.concatenate([lax.slice_in_dim(u, i, n, axis=ax),
+                            lax.slice_in_dim(u, 0, i, axis=ax)], ax)
+
 
 def _shift(u, d: Tuple[int, int, int], k: int, axes: Tuple[int, int, int]):
     """u(i + k*d) via one roll per spatial axis the shift moves along (a
     zero shift would lower to a zero-size slice, which Mosaic rejects)."""
     for dk, ax in zip(d, axes):
         if k * dk:
-            u = jnp.roll(u, -k * dk, axis=ax)
+            u = _roll(u, -k * dk, ax)
     return u
 
 
 def _ppm_limited(at, side: int):
     """CW84 limited-parabola surface value toward -d (side=0) or +d
     (side=1), where ``at(k)`` gives u(i + k*d)."""
+    add, sub, mul = lax.add, lax.sub, lax.mul
     u = at(0)
     um2 = at(-2)
     um1 = at(-1)
     up1 = at(1)
     up2 = at(2)
-    ul = (7.0 / 12.0) * (um1 + u) - (1.0 / 12.0) * (um2 + up1)
-    ur = (7.0 / 12.0) * (u + up1) - (1.0 / 12.0) * (um1 + up2)
-    extremum = (ur - u) * (u - ul) <= 0.0
-    du = ur - ul
-    u6 = 6.0 * (u - 0.5 * (ul + ur))
-    ul_lim = jnp.where(du * u6 > du * du, 3.0 * u - 2.0 * ur, ul)
-    ur_lim = jnp.where(-(du * du) > du * u6, 3.0 * u - 2.0 * ul, ur)
-    ul = jnp.where(extremum, u, ul_lim)
-    ur = jnp.where(extremum, u, ur_lim)
+    ul = sub(mul(7.0 / 12.0, add(um1, u)), mul(1.0 / 12.0, add(um2, up1)))
+    ur = sub(mul(7.0 / 12.0, add(u, up1)), mul(1.0 / 12.0, add(um1, up2)))
+    extremum = lax.le(mul(sub(ur, u), sub(u, ul)), 0.0)
+    du = sub(ur, ul)
+    u6 = mul(6.0, sub(u, mul(0.5, add(ul, ur))))
+    ul_lim = lax.select(lax.gt(mul(du, u6), mul(du, du)),
+                        sub(mul(3.0, u), mul(2.0, ur)), ul)
+    ur_lim = lax.select(lax.gt(lax.neg(mul(du, du)), mul(du, u6)),
+                        sub(mul(3.0, u), mul(2.0, ul)), ur)
+    ul = lax.select(extremum, u, ul_lim)
+    ur = lax.select(extremum, u, ur_lim)
     return ur if side else ul
 
 
@@ -80,38 +99,61 @@ def _ppm_side(u, d, side: int, axes):
     return _ppm_limited(lambda k: _shift(u, d, k, axes), side)
 
 
+def _field(u, k: int):
+    """``u[k]``: field ``k`` of a block with the field axis leading."""
+    return lax.index_in_dim(u, k, keepdims=False)
+
+
+def _lead(x):
+    """``x`` with a unit field axis, to broadcast against (F, ...)."""
+    return lax.expand_dims(x, (0,))
+
+
 def _prim(u, gamma):
     """u: (F, ...) -> rho, vx, vy, vz, p (field axis leading)."""
-    rho = jnp.maximum(u[0], 1e-10)
-    vx, vy, vz = u[1] / rho, u[2] / rho, u[3] / rho
-    ke = 0.5 * rho * (vx * vx + vy * vy + vz * vz)
-    p = jnp.maximum((gamma - 1.0) * (u[4] - ke), 1e-12)
+    add, sub, mul, div = lax.add, lax.sub, lax.mul, lax.div
+    rho = lax.max(_field(u, 0), 1e-10)
+    vx, vy, vz = (div(_field(u, 1), rho), div(_field(u, 2), rho),
+                  div(_field(u, 3), rho))
+    ke = mul(mul(0.5, rho), add(add(mul(vx, vx), mul(vy, vy)), mul(vz, vz)))
+    p = lax.max(mul(gamma - 1.0, sub(_field(u, 4), ke)), 1e-12)
     return rho, vx, vy, vz, p
 
 
 def _phys_flux(u, axis, gamma):
+    mul = lax.mul
     rho, vx, vy, vz, p = _prim(u, gamma)
     v = (vx, vy, vz)[axis]
-    f = [rho * v, u[1] * v, u[2] * v, u[3] * v, (u[4] + p) * v]
-    f[1 + axis] = f[1 + axis] + p
-    return jnp.stack(f)
+    f = [mul(rho, v), mul(_field(u, 1), v), mul(_field(u, 2), v),
+         mul(_field(u, 3), v), mul(lax.add(_field(u, 4), p), v)]
+    f[1 + axis] = lax.add(f[1 + axis], p)
+    return lax.concatenate([_lead(x) for x in f], 0)
 
 
 def _central_upwind(uL, uR, axis, gamma):
+    add, sub, mul, div = lax.add, lax.sub, lax.mul, lax.div
     rhoL, vxL, vyL, vzL, pL = _prim(uL, gamma)
     rhoR, vxR, vyR, vzR, pR = _prim(uR, gamma)
     vL = (vxL, vyL, vzL)[axis]
     vR = (vxR, vyR, vzR)[axis]
-    cL = jnp.sqrt(gamma * pL / rhoL)
-    cR = jnp.sqrt(gamma * pR / rhoR)
-    ap = jnp.maximum(jnp.maximum(vL + cL, vR + cR), 0.0)
-    am = jnp.minimum(jnp.minimum(vL - cL, vR - cR), 0.0)
+    cL = lax.sqrt(div(mul(gamma, pL), rhoL))
+    cR = lax.sqrt(div(mul(gamma, pR), rhoR))
+    ap = lax.max(lax.max(add(vL, cL), add(vR, cR)), 0.0)
+    am = lax.min(lax.min(sub(vL, cL), sub(vR, cR)), 0.0)
     fL = _phys_flux(uL, axis, gamma)
     fR = _phys_flux(uR, axis, gamma)
-    span = ap - am
-    inv = jnp.where(span > 1e-12, 1.0 / jnp.maximum(span, 1e-12), 0.0)
-    flux = (ap * fL - am * fR) * inv + (ap * am) * inv * (uR - uL)
-    return jnp.where(span > 1e-12, flux, 0.5 * (fL + fR))
+    span = sub(ap, am)
+    inv = lax.select(lax.gt(span, 1e-12), div(1.0, lax.max(span, 1e-12)),
+                     lax.full_like(span, 0.0))
+    flux = mul(sub(mul(_lead(ap), fL), mul(_lead(am), fR)), _lead(inv))
+    jump = mul(mul(ap, am), inv)
+    du = sub(uR, uL)
+    flux = add(flux, mul(_lead(jump), du))
+    wide = lax.gt(span, 1e-12)
+    mean = mul(0.5, add(fL, fR))
+    return lax.select(lax.broadcast_in_dim(wide, flux.shape,
+                                           tuple(range(1, flux.ndim))),
+                      flux, mean)
 
 
 def _rhs_field_block(u, h, gamma: float, ghost: int, subgrid: int,
@@ -129,8 +171,8 @@ def _rhs_field_block(u, h, gamma: float, ghost: int, subgrid: int,
         for (w, pL, sL, pR, sR) in FACE_QUAD[axis]:
             uL = _ppm_side(u, DIR_PAIRS[pL], sL, axes)
             uR = _shift(_ppm_side(u, DIR_PAIRS[pR], sR, axes), e, 1, axes)
-            f = w * _central_upwind(uL, uR, axis, gamma)
-            face = f if face is None else face + f
+            f = lax.mul(w, _central_upwind(uL, uR, axis, gamma))
+            face = f if face is None else lax.add(face, f)
         # divergence over the interior
         def _slice(arr, lo):
             idx = [slice(None)] * arr.ndim
@@ -156,7 +198,7 @@ _PLANE_AXES = (-3, -2)                    # y, z of an x-plane (F, P, P, T)
 def _plane_at(load, i: int, d, k: int):
     """u(i + k*d) restricted to x-plane ``i``; ``load(j)`` reads plane j.
     The x component picks the plane, so ``_shift`` only rolls y and z."""
-    return _shift(load(i + k * d[0]), (0, d[1], d[2]), k,
+    return _shift(load(lax.add(i, k * d[0])), (0, d[1], d[2]), k,
                   (0,) + _PLANE_AXES)
 
 
@@ -170,10 +212,10 @@ def _face_plane(load, i, axis: int, gamma: float):
         if axis == 0:                     # the right state is on plane i+1
             uR = _ppm_limited(lambda k: _plane_at(load, i + 1, dR, k), sR)
         else:
-            uR = jnp.roll(_ppm_limited(lambda k: _plane_at(load, i, dR, k),
-                                       sR), -1, axis=_PLANE_AXES[axis - 1])
-        f = w * _central_upwind(uL, uR, axis, gamma)
-        face = f if face is None else face + f
+            uR = _roll(_ppm_limited(lambda k: _plane_at(load, i, dR, k),
+                                    sR), -1, _PLANE_AXES[axis - 1])
+        f = lax.mul(w, _central_upwind(uL, uR, axis, gamma))
+        face = f if face is None else lax.add(face, f)
     return face
 
 
@@ -262,24 +304,43 @@ def hydro_rhs_pallas(u_slots: jax.Array, *, h: Optional[float] = None,
     """
     if (h is None) == (h_slots is None):
         raise ValueError("pass exactly one of h / h_slots")
-    n, f, p = u_slots.shape[0], u_slots.shape[1], u_slots.shape[2]
+    call = rhs_program(layout, tuple(u_slots.shape), jnp.dtype(u_slots.dtype),
+                       h, gamma, ghost, subgrid)
+    return call(u_slots) if h_slots is None else call(u_slots, h_slots)
+
+
+@functools.lru_cache(maxsize=None)
+def rhs_program(layout: str, shape: Tuple[int, ...], dtype, h: Optional[float],
+                gamma: float, ghost: int, subgrid: int):
+    """The jitted kernel call for one static key; ``h=None`` takes a traced
+    ``h_slots`` as its second argument.
+
+    Every program that calls the kernel at one key (a region's gather,
+    prefix and ring programs at one bucket, the three RK stages, the fused
+    wrapper) calls this one ``jax.jit`` object, so JAX's trace cache traces
+    the kernel once per process and key: one trace for the Mosaic branch
+    and one for the interpret branch of ``backend.pallas_call``
+    (``backend.kernel_traces`` counts them).
+    """
+    n, f, p = shape[0], shape[1], shape[2]
     s = subgrid
     kw = dict(gamma=gamma, ghost=ghost, subgrid=subgrid)
-    out_grid = jax.ShapeDtypeStruct((n, f, s, s, s), u_slots.dtype)
+    name = f"hydro_rhs_{layout}"
 
     if layout == "slot_grid":
-        if h_slots is None:
-            return pallas_call(
+        out_shape = jax.ShapeDtypeStruct((n, f, s, s, s), dtype)
+        if h is not None:
+            call = pallas_call(
                 functools.partial(_kernel_slot_grid, h=h, **kw),
                 grid=(n,),
                 in_specs=[pl.BlockSpec((1, f, p, p, p),
                                        lambda i: (i, 0, 0, 0, 0))],
                 out_specs=pl.BlockSpec((1, f, s, s, s),
                                        lambda i: (i, 0, 0, 0, 0)),
-                out_shape=out_grid,
-                vmem_limit_bytes=VMEM_LIMIT_BYTES,
-            )(u_slots)
-        return pallas_call(
+                out_shape=out_shape, name=name,
+                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+            return jax.jit(call)
+        call = pallas_call(
             functools.partial(_kernel_slot_grid_h, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
@@ -288,34 +349,49 @@ def hydro_rhs_pallas(u_slots: jax.Array, *, h: Optional[float] = None,
                                        lambda i, h_ref: (i, 0, 0, 0, 0))],
                 out_specs=pl.BlockSpec((1, f, s, s, s),
                                        lambda i, h_ref: (i, 0, 0, 0, 0))),
-            out_shape=out_grid,
-            vmem_limit_bytes=VMEM_LIMIT_BYTES,
-        )(jnp.reshape(h_slots, (n,)), u_slots)
+            out_shape=out_shape, name=name,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES)
+        return jax.jit(lambda u, h_slots: call(jnp.reshape(h_slots, (n,)),
+                                               u))
 
     if layout == "slot_lane":
-        # tasks on the minor (lane) axis: (F, P, P, P, slots), tiled by a
-        # full 128-lane row where the bucket divides into them, else taken
-        # whole (a block's minor dimension must be a multiple of 128 or
-        # the whole array dimension)
-        t = LANES if n % LANES == 0 else n
-        u_t = u_slots.transpose(1, 2, 3, 4, 0)
+        # tasks on the minor (lane) axis: (F, P, P, P, slots).  A bucket of
+        # up to 128 tasks is one block (a block's minor dimension must be a
+        # multiple of 128 or the whole array dimension); a larger bucket is
+        # padded to whole 128-lane tiles, so that VMEM holds one tile of
+        # 128 tasks whatever the bucket
+        t = min(n, LANES)
+        m = -(-n // t) * t
+
+        def pad(x, fill):
+            if m == n:
+                return x
+            return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, m - n),),
+                           constant_values=fill)
+
+        def tasks(out):
+            return (out if m == n else out[..., :n]).transpose(4, 0, 1, 2, 3)
+
         in_specs = [pl.BlockSpec((f, p, p, p, t), lambda i: (0, 0, 0, 0, i))]
         lane = dict(
-            grid=(n // t,),
+            grid=(m // t,),
             out_specs=pl.BlockSpec((f, s, s, s, t),
                                    lambda i: (0, 0, 0, 0, i)),
-            out_shape=jax.ShapeDtypeStruct((f, s, s, s, n), u_slots.dtype),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES)
-        if h_slots is None:
-            out = pallas_call(
+            out_shape=jax.ShapeDtypeStruct((f, s, s, s, m), dtype),
+            name=name, vmem_limit_bytes=VMEM_LIMIT_BYTES)
+        if h is not None:
+            call = pallas_call(
                 functools.partial(_kernel_slot_lane, h=h, **kw),
-                in_specs=in_specs, **lane)(u_t)
-        else:
-            out = pallas_call(
-                functools.partial(_kernel_slot_lane_h, **kw),
-                in_specs=in_specs + [pl.BlockSpec((1, t), lambda i: (0, i))],
-                **lane)(u_t, jnp.reshape(h_slots, (1, n)))
-        return out.transpose(4, 0, 1, 2, 3)
+                in_specs=in_specs, **lane)
+            return jax.jit(lambda u: tasks(call(
+                pad(u.transpose(1, 2, 3, 4, 0), 0.0))))
+        call = pallas_call(
+            functools.partial(_kernel_slot_lane_h, **kw),
+            in_specs=in_specs + [pl.BlockSpec((1, t), lambda i: (0, i))],
+            **lane)
+        return jax.jit(lambda u, h_slots: tasks(call(
+            pad(u.transpose(1, 2, 3, 4, 0), 0.0),
+            pad(jnp.reshape(h_slots, (1, n)), 1.0))))
 
     raise ValueError(f"unknown layout {layout!r}")
 
@@ -401,8 +477,8 @@ def _kernel_flux(recon_ref, out_ref, *, h, gamma, ghost, subgrid):
         for (w, pL, sL, pR, sR) in FACE_QUAD[axis]:
             uL = recon[pL, sL]
             uR = _shift(recon[pR, sR], e, 1, axes)
-            f = w * _central_upwind(uL, uR, axis, gamma)
-            face = f if face is None else face + f
+            f = lax.mul(w, _central_upwind(uL, uR, axis, gamma))
+            face = f if face is None else lax.add(face, f)
         hi = face[:, g:g + s, g:g + s, g:g + s]
         lo_idx = [slice(g, g + s)] * 3
         lo_idx[axis] = slice(g - 1, g - 1 + s)

@@ -73,6 +73,7 @@ def _compile(fn, *specs):
     ("slot_grid", False, BUCKET),
     ("slot_grid", True, BUCKET),
     ("slot_lane", False, LANE_BUCKET),
+    ("slot_lane", False, BUCKET),
 ])
 def test_hydro_rhs_kernel_compiles_for_v5e(one_chip, layout, traced_h, n):
     if traced_h:
@@ -93,7 +94,8 @@ def test_gravity_kernel_compiles_for_v5e(one_chip):
 
 
 def test_default_xla_body_compiles_for_v5e(one_chip):
-    """The body the main path runs by default: vmap of the jnp stencil over
-    one bucket of the paper's Table II configuration."""
+    """The body a uniform scenario runs where ``slot_lane`` is not selected
+    (``uniform_batched_body``): vmap of the jnp stencil over one bucket of
+    the paper's Table II configuration."""
     body = UniformSedovScenario(CONFIG).batched_body
     _compile(body, _subgrids(BUCKET, one_chip))
